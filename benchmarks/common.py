@@ -48,7 +48,9 @@ def ensure_host_devices(n: int) -> None:
     jax initializes (the prod-backend benchmarks call it from their
     __main__ guards). Appends the XLA flag if absent; if the environment
     already pins a SMALLER count, raises the count to ``n`` (and says so)
-    rather than letting the backend fail with a device-count error."""
+    rather than letting the backend fail with a device-count error. The
+    flag applies to the CPU platform only: a CPU run selects it with
+    ``JAX_PLATFORMS=cpu``; the platform is never chosen here."""
     import os
     import re
     flags = os.environ.get("XLA_FLAGS", "")
@@ -61,7 +63,6 @@ def ensure_host_devices(n: int) -> None:
         flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
                        f"--xla_force_host_platform_device_count={n}", flags)
     os.environ["XLA_FLAGS"] = flags.strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def time_to_target(values: np.ndarray, per_step_time: float, target: float,

@@ -76,14 +76,14 @@ def main():
                  "only (use --backend prod)")
 
     if args.backend == "prod":
-        # the prod lane needs one host device per worker; both env vars must
-        # be set before jax initializes (append — don't clobber any flags
-        # the user already exported)
+        # the prod lane needs one device per worker; on the CPU platform
+        # (JAX_PLATFORMS=cpu) that is one host device each, a flag that
+        # must be set before jax initializes (append — don't clobber any
+        # flags the user already exported)
         flag = f"--xla_force_host_platform_device_count={M}"
         existing = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in existing:
             os.environ["XLA_FLAGS"] = (existing + " " + flag).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax
     import jax.numpy as jnp

@@ -21,6 +21,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# grid (B·H, q blocks, kv blocks): the kv axis carries the online-softmax
+# (or gradient) accumulators, so it runs in order
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
@@ -108,12 +112,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     def kvmap(bh, qi, ki):
         return (bh // Hq, (bh % Hq) // G, ki, 0)
 
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # pragma: no cover - older pallas API
-        compiler_params = None
-
     out_specs = pl.BlockSpec((1, 1, bq, d), qmap)
     out_shape = jax.ShapeDtypeStruct((B, Hq, Sq, d), q.dtype)
     if return_lse:
@@ -138,7 +136,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=_COMPILER_PARAMS,
     )(q, k, v)
 
 
@@ -262,13 +260,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
             return (bh // Hq, bh % Hq, qi)
         return f
 
-    try:
-        cp = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-        cp_kw = {"compiler_params": cp}
-    except Exception:  # pragma: no cover
-        cp_kw = {}
-
     # ---- pass 1: dq, grid (B·Hq, nq, nk) -----------------------------------
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, bq=bq, bk=bk, nk=nk,
@@ -285,7 +276,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
         out_specs=pl.BlockSpec((1, 1, bq, d), q_of(1)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret, **cp_kw,
+        interpret=interpret, compiler_params=_COMPILER_PARAMS,
     )(q, k, v, do, lse, delta)
 
     # ---- pass 2: dk/dv per q-head, grid (B·Hq, nk, nq) ---------------------
@@ -311,7 +302,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
                    jax.ShapeDtypeStruct((B, Hq, Sk, d), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret, **cp_kw,
+        interpret=interpret, compiler_params=_COMPILER_PARAMS,
     )(q, k, v, do, lse, delta)
     # GQA: sum the per-q-head partials within each kv group
     dk = dk_h.reshape(B, Hkv, G, Sk, d).sum(axis=2).astype(k.dtype)

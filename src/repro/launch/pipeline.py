@@ -90,13 +90,23 @@ from repro.optim.optimizers import Optimizer
 # ---------------------------------------------------------------------------
 
 
+def _donated(x) -> bool:
+    """True for an array already consumed by a donating stage."""
+    is_deleted = getattr(x, "is_deleted", None)
+    return is_deleted is not None and is_deleted()
+
+
 def _is_ready(x) -> bool:
     """Non-blocking readiness probe; arrays already consumed by a donating
-    stage count as retired."""
-    try:
-        return bool(x.is_ready())
-    except Exception:
-        return True
+    stage count as retired. Any other failure (a device execution that
+    failed surfaces here) propagates."""
+    return _donated(x) or bool(x.is_ready())
+
+
+def _wait(x) -> None:
+    """Block until ``x`` is computed; a donated array has retired."""
+    if not _donated(x):
+        jax.block_until_ready(x)
 
 
 class StageTimeline:
@@ -182,10 +192,7 @@ class StageTimeline:
     def finalize(self) -> None:
         """Block on every outstanding fence and close its event."""
         for ev, fence in self._pending:
-            try:
-                jax.block_until_ready(fence)
-            except Exception:
-                pass
+            _wait(fence)
             ev["complete"] = self._clock()
         self._pending = []
 
@@ -841,10 +848,7 @@ class PipelineEngine:
         self._graveyard = [(f, p) for f, p in self._graveyard
                            if not _is_ready(f)]
         while len(self._graveyard) >= self.max_inflight_steps:
-            try:
-                jax.block_until_ready(self._graveyard[0][0])
-            except Exception:
-                pass
+            _wait(self._graveyard[0][0])
             self._graveyard.pop(0)
             self._graveyard = [(f, p) for f, p in self._graveyard
                                if not _is_ready(f)]

@@ -393,7 +393,11 @@ class StreamEngine:
         self.describe = describe
         self.abstract_args = abstract_args or {}
         self.max_inflight_steps = int(max_inflight_steps)
-        self.board = SignalBoard()
+        # plane payloads are whole parameter planes, so retain only what a
+        # reader can still ask for: plane t+2 is pushed by step t+1's mix,
+        # which waits on step t's clock (its w), which waits on every
+        # forward slice of step t — by then nothing reads plane t
+        self.board = SignalBoard(keep=2)
 
         n = min(int(n_streams), self.R + 2)
         G = len(self.group_names)

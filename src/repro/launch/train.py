@@ -56,23 +56,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.5: top-level export with check_vma/axis_names kwargs
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False,
-                          axis_names=set(axis_names))
-except ImportError:  # jax 0.4.x: experimental API; partial-manual (auto=)
-    # subgroup sharding trips an XLA CHECK in this generation, so fall back
-    # to fully-manual shard_map — the body sees model-axis-replicated
-    # shards (tensor parallelism folds into replication; numerics are
-    # unchanged, memory is the 0.4.x price)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-        return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax.flatten_util import ravel_pytree
@@ -106,6 +90,13 @@ class ProdStep:
 
     def lower(self):
         return self.fn.lower(*self.abstract_args)
+
+
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
+    """``jax.shard_map`` manual over ``axis_names`` (the worker axes) and
+    GSPMD-auto over the rest ('model'), replication checks off."""
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False, axis_names=set(axis_names))
 
 
 def _abstract_batch(cfg: ModelConfig, shape: ShapeConfig, dtype=None):
@@ -161,18 +152,9 @@ def _apply_grad_specs(grads, grad_specs):
     lane and the per-slice pipeline stages so both compile identical HLO."""
     if grad_specs is None:
         return grads
-    try:
-        return jax.tree.map(
-            lambda g, s: jax.lax.with_sharding_constraint(g, s),
-            grads, grad_specs)
-    except RuntimeError as e:
-        # raw-PartitionSpec constraints need a mesh context; the jax 0.4.x
-        # fully-manual shard_map body has none, and the constraint is a
-        # no-op there anyway (model axes fold into replication —
-        # DESIGN.md §2). Skip only that failure.
-        if "non-empty mesh" not in str(e):
-            raise
-        return grads
+    return jax.tree.map(
+        lambda g, s: jax.lax.with_sharding_constraint(g, s),
+        grads, grad_specs)
 
 
 def forward_lane(loss_fn: Callable, *, fb_ratio: int = 1,
@@ -389,6 +371,23 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
+def _per_shard(kernel: Callable) -> Callable:
+    """Run a Pallas kernel inside the worker-manual lane body. XLA cannot
+    partition a compiled (Mosaic) kernel over the mesh axes the body
+    leaves to GSPMD ('model'), so the call is made manual over those too;
+    its operands — plane buffers and mix scalars — are replicated over
+    them, and every shard computes its full copy."""
+    def call(*args):
+        mesh = jax.sharding.get_abstract_mesh()
+        auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                if t != jax.sharding.AxisType.Manual}
+        if not auto:
+            return kernel(*args)
+        return jax.shard_map(kernel, in_specs=P(), out_specs=P(),
+                             axis_names=auto, check_vma=False)(*args)
+    return call
+
+
 def _ring_exchange(plane, w, shift_idx, M: int, ax, shifts: Sequence[int],
                    alive=None):
     """One push-sum ring hop on the flat plane: ship every group buffer
@@ -467,10 +466,10 @@ def gossip_plane_lane(part: FlatPartition, M: int, ax,
             return lambda plane, resid, w, shift_idx, alive=None: (
                 plane, resid, w)
         if use_pallas:
-            qfn = lambda x, r: _quantize_plane_kernel(
-                x, r, interpret=interpret)
-            dqfn = lambda x, q, s, a, b: _dequant_mix_kernel(
-                x, q, s, None, a, b, interpret=interpret)
+            qfn = _per_shard(lambda x, r: _quantize_plane_kernel(
+                x, r, interpret=interpret))
+            dqfn = _per_shard(lambda x, q, s, a, b: _dequant_mix_kernel(
+                x, q, s, None, a, b, interpret=interpret))
         else:
             qfn = quantize_plane_ref
             dqfn = lambda x, q, s, a, b: dequant_mix_ref(x, q, s, None, a, b)
@@ -503,6 +502,8 @@ def gossip_plane_lane(part: FlatPartition, M: int, ax,
         raise ValueError(f"unknown wire dtype {wire!r}")
     if M == 1:
         return lambda plane, w, shift_idx, alive=None: (plane, w)
+    pure_mix = _per_shard(lambda x, r, a, b: _gossip_mix_kernel(
+        x, r, None, a, b, interpret=interpret))
 
     def mix(plane, w, shift_idx, alive=None):
         recv, w_keep, rw, use = _ring_exchange(plane, w, shift_idx, M, ax,
@@ -512,9 +513,7 @@ def gossip_plane_lane(part: FlatPartition, M: int, ax,
         mixed = {}
         for name, mine in plane.items():
             if use_pallas:
-                mx = _gossip_mix_kernel(
-                    mine, recv[name], None, w_keep / denom, rw / denom,
-                    interpret=interpret)
+                mx = pure_mix(mine, recv[name], w_keep / denom, rw / denom)
             else:
                 mf = (w_keep * mine.astype(jnp.float32)
                       + rw * recv[name].astype(jnp.float32)) / denom
@@ -551,16 +550,16 @@ def gossip_fused_lane(part: FlatPartition, M: int, ax,
     untouched."""
     interpret = _resolve_interpret(interpret)
     if use_pallas:
-        op = lambda x, r, u, a, b: _gossip_mix_kernel(
-            x, r, u, a, b, interpret=interpret)
+        op = _per_shard(lambda x, r, u, a, b: _gossip_mix_kernel(
+            x, r, u, a, b, interpret=interpret))
     else:
         from repro.kernels.ref import gossip_mix_ref as op
     if wire == "int8":
         if use_pallas:
-            qfn = lambda x, r: _quantize_plane_kernel(
-                x, r, interpret=interpret)
-            dqfn = lambda x, q, s, u, a, b: _dequant_mix_kernel(
-                x, q, s, u, a, b, interpret=interpret)
+            qfn = _per_shard(lambda x, r: _quantize_plane_kernel(
+                x, r, interpret=interpret))
+            dqfn = _per_shard(lambda x, q, s, u, a, b: _dequant_mix_kernel(
+                x, q, s, u, a, b, interpret=interpret))
         else:
             qfn = quantize_plane_ref
             dqfn = dequant_mix_ref

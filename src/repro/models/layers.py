@@ -7,6 +7,7 @@ the axis tree for the sharding rules in ``repro.launch.sharding``.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -66,8 +67,10 @@ def logical_axes(specs):
 
 
 def _path_hash(path) -> int:
+    """Stable per-leaf fold-in value: the same in every process (the
+    builtin ``hash`` of a str is salted per process)."""
     s = jax.tree_util.keystr(path)
-    return abs(hash(s)) % (2**31)
+    return zlib.crc32(s.encode()) % (2**31)
 
 
 def param_count(params) -> int:

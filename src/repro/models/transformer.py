@@ -42,16 +42,7 @@ ACTIVATION_PSPEC = None
 
 def constrain_h(h):
     if ACTIVATION_PSPEC is not None:
-        try:
-            h = jax.lax.with_sharding_constraint(h, ACTIVATION_PSPEC)
-        except RuntimeError as e:
-            # Raw-PartitionSpec constraints need a mesh context, which the
-            # jax 0.4.x fully-manual shard_map body does not provide. The
-            # constraint is a no-op under that fallback anyway (the body
-            # sees model-axis-replicated shards, DESIGN.md §2), so skip it
-            # rather than fail the trace — but only that specific failure.
-            if "non-empty mesh" not in str(e):
-                raise
+        h = jax.lax.with_sharding_constraint(h, ACTIVATION_PSPEC)
     return h
 
 

@@ -160,3 +160,25 @@ class TestParamSpecs:
         p1 = init_params(rng, specs)
         p2 = init_params(rng, specs)
         np.testing.assert_array_equal(np.asarray(p1["a"]), np.asarray(p2["a"]))
+
+    def test_init_same_in_every_process(self):
+        """Seeded weights do not depend on the process: two interpreters
+        with different string-hash salts build identical parameters."""
+        import os
+        import subprocess
+        import sys
+        code = ("import jax, numpy as np\n"
+                "from repro.models.layers import ParamSpec, init_params\n"
+                "p = init_params(jax.random.PRNGKey(0), "
+                "{'a': ParamSpec((4, 8), (None, None))})\n"
+                "print(repr(np.asarray(p['a']).tolist()))\n")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        outs = []
+        for salt in ("1", "2"):
+            env = dict(os.environ, PYTHONPATH=src, PYTHONHASHSEED=salt,
+                       JAX_PLATFORMS="cpu")
+            r = subprocess.run([sys.executable, "-c", code], env=env,
+                               capture_output=True, text=True, timeout=300)
+            assert r.returncode == 0, r.stderr
+            outs.append(r.stdout)
+        assert outs[0] == outs[1]

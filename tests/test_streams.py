@@ -329,6 +329,30 @@ class TestStreamMechanics:
             assert np.asarray(leaf).shape[1:] == np.asarray(ref).shape
         be.engine.close()
 
+    def test_board_retains_two_plane_versions(self):
+        """Each plane payload is a whole parameter plane: after several
+        steps the board holds only the two newest versions, so device
+        memory stays flat instead of growing one plane per step."""
+        loss_fn, params = _mlp_problem()
+        be = make_backend("prod", "layup", M=1, loss_fn=loss_fn,
+                          optimizer=momentum(0.9), schedule=constant(0.05),
+                          fb_ratio=2, update_delay=1, overlap=True,
+                          streams=3, measure_drift=False)
+        st = be.init(jax.random.PRNGKey(0), params)
+        steps = 6
+        for t in range(steps):
+            st, m = be.step(st, _batch(t, 1, 8), None)
+            assert np.isfinite(float(m["loss"]))
+        be.engine.finalize()
+        slot = be.engine._plane_slot(next(iter(be.part.group_sizes)))
+        board = be.engine.board
+        assert board.read(slot) == steps
+        for v in (steps, steps - 1):
+            assert board.wait_until(slot, v, timeout=0.1) is not None
+        with pytest.raises(KeyError, match="evicted"):
+            board.wait_until(slot, steps - 2, timeout=0.1)
+        be.engine.close()
+
     def test_reinit_resets_board_and_timeline(self):
         loss_fn, params = _mlp_problem()
         be = make_backend("prod", "layup", M=1, loss_fn=loss_fn,
