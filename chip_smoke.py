@@ -241,6 +241,7 @@ def phase_main(cfg, seed: int, *, seq: int = SEQ, batch: int = BATCH,
     from repro.data.synthetic import lm_batch_for
     from repro.launch.train import make_decoupled_state, make_step
     from repro.models import build_model
+    from repro.models.layers import attention_path_counts
     from repro.optim import constant, momentum
 
     model = build_model(cfg)
@@ -269,12 +270,19 @@ def phase_main(cfg, seed: int, *, seq: int = SEQ, batch: int = BATCH,
                   update_delay=D)
 
     # -- monolithic decoupled step ---------------------------------------
+    before = attention_path_counts()
     step = make_step(model, mesh, shape, **common)
     log(f"  {step.describe}")
     t0 = time.perf_counter()
     compiled = step.lower().compile()
     log(f"  [bring-up] monolithic compile: {time.perf_counter() - t0:.2f} s")
     _compiled_memory("monolithic", compiled)
+    paths = {k: n - before[k] for k, n in attention_path_counts().items()}
+    log(f"  attention calls traced for the step, by path: {paths}")
+    want, other = (("kernel", "jnp") if jax.default_backend() == "tpu"
+                   else ("jnp", "kernel"))
+    check(paths[want] > 0 and paths[other] == 0,
+          f"the model's attention takes the {want} path on every call")
 
     def mono(state, b, t):
         state, m = compiled(state, b, jnp.int32(t), jnp.int32(0))

@@ -67,6 +67,7 @@ from repro.core.layerview import (
     version_metrics,
 )
 from repro.kernels.gossip_mix import gossip_mix as _gossip_mix_kernel
+from repro.kernels.ops import per_shard
 from repro.kernels.quantize import dequant_mix as _dequant_mix_kernel
 from repro.kernels.quantize import quantize_plane as _quantize_plane_kernel
 from repro.kernels.ref import dequant_mix_ref, quantize_plane_ref
@@ -94,9 +95,15 @@ class ProdStep:
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
     """``jax.shard_map`` manual over ``axis_names`` (the worker axes) and
-    GSPMD-auto over the rest ('model'), replication checks off."""
+    over every axis of size 1, which GSPMD has nothing to split over, and
+    GSPMD-auto over the rest ('model' when it is split), replication checks
+    off. A compiled Pallas kernel in the body then needs no shard_map of its
+    own on a (M, 1) mesh (``repro.kernels.ops.per_shard``): nested one per
+    attention call, they cost the stablelm-1.6b step 0.8 GB more compiled
+    HBM (compiled for a v5e)."""
+    names = set(axis_names) | {a for a, n in mesh.shape.items() if n == 1}
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False, axis_names=set(axis_names))
+                      check_vma=False, axis_names=names)
 
 
 def _abstract_batch(cfg: ModelConfig, shape: ShapeConfig, dtype=None):
@@ -389,23 +396,6 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
-def _per_shard(kernel: Callable) -> Callable:
-    """Run a Pallas kernel inside the worker-manual lane body. XLA cannot
-    partition a compiled (Mosaic) kernel over the mesh axes the body
-    leaves to GSPMD ('model'), so the call is made manual over those too;
-    its operands — plane buffers and mix scalars — are replicated over
-    them, and every shard computes its full copy."""
-    def call(*args):
-        mesh = jax.sharding.get_abstract_mesh()
-        auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
-                if t != jax.sharding.AxisType.Manual}
-        if not auto:
-            return kernel(*args)
-        return jax.shard_map(kernel, in_specs=P(), out_specs=P(),
-                             axis_names=auto, check_vma=False)(*args)
-    return call
-
-
 def _mix_scope(name: str):
     """The named scope of one plane buffer's part of the gossip mix,
     ``mix.<buffer>``, and ``mix.weight`` for the push-sum weights. A
@@ -496,9 +486,9 @@ def gossip_plane_lane(part: FlatPartition, M: int, ax,
             return lambda plane, resid, w, shift_idx, alive=None: (
                 plane, resid, w)
         if use_pallas:
-            qfn = _per_shard(lambda x, r: _quantize_plane_kernel(
+            qfn = per_shard(lambda x, r: _quantize_plane_kernel(
                 x, r, interpret=interpret))
-            dqfn = _per_shard(lambda x, q, s, a, b: _dequant_mix_kernel(
+            dqfn = per_shard(lambda x, q, s, a, b: _dequant_mix_kernel(
                 x, q, s, None, a, b, interpret=interpret))
         else:
             qfn = quantize_plane_ref
@@ -536,7 +526,7 @@ def gossip_plane_lane(part: FlatPartition, M: int, ax,
         raise ValueError(f"unknown wire dtype {wire!r}")
     if M == 1:
         return lambda plane, w, shift_idx, alive=None: (plane, w)
-    pure_mix = _per_shard(lambda x, r, a, b: _gossip_mix_kernel(
+    pure_mix = per_shard(lambda x, r, a, b: _gossip_mix_kernel(
         x, r, None, a, b, interpret=interpret))
 
     def mix(plane, w, shift_idx, alive=None):
@@ -588,15 +578,15 @@ def gossip_fused_lane(part: FlatPartition, M: int, ax,
     untouched."""
     interpret = _resolve_interpret(interpret)
     if use_pallas:
-        op = _per_shard(lambda x, r, u, a, b: _gossip_mix_kernel(
+        op = per_shard(lambda x, r, u, a, b: _gossip_mix_kernel(
             x, r, u, a, b, interpret=interpret))
     else:
         from repro.kernels.ref import gossip_mix_ref as op
     if wire == "int8":
         if use_pallas:
-            qfn = _per_shard(lambda x, r: _quantize_plane_kernel(
+            qfn = per_shard(lambda x, r: _quantize_plane_kernel(
                 x, r, interpret=interpret))
-            dqfn = _per_shard(lambda x, q, s, u, a, b: _dequant_mix_kernel(
+            dqfn = per_shard(lambda x, q, s, u, a, b: _dequant_mix_kernel(
                 x, q, s, u, a, b, interpret=interpret))
         else:
             qfn = quantize_plane_ref

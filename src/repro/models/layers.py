@@ -6,6 +6,7 @@ the axis tree for the sharding rules in ``repro.launch.sharding``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import zlib
 from dataclasses import dataclass
@@ -15,6 +16,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels import splash
+from repro.kernels.ops import auto_axes
 
 # ---------------------------------------------------------------------------
 # Parameter specs
@@ -163,11 +167,52 @@ def sinusoidal_positions(seq_len: int, d_model: int) -> jnp.ndarray:
 
 NEG_INF = -1e30
 
-# When True, full-sequence attention dispatches to the Pallas kernels
-# (flash fwd + bwd via custom_vjp) instead of the pure-jnp flash. On this
-# CPU container the kernels run in interpret mode (slow — tests only); on
-# TPU they are the deployment path. Set via repro.models.layers.USE_PALLAS.
-USE_PALLAS = False
+# full-sequence attention calls traced in this process, by path
+_PATH_COUNTS: collections.Counter = collections.Counter()
+
+
+def attention_path_counts() -> Dict[str, int]:
+    """How many full-sequence attention calls were traced on each path:
+    ``{"kernel": n, "jnp": m}``. A trace-time count: a layer scan traces
+    its body once."""
+    return {"kernel": _PATH_COUNTS["kernel"], "jnp": _PATH_COUNTS["jnp"]}
+
+
+def attention_path(q_shape, k_shape, *, causal: bool, window: int,
+                   arange: bool) -> str:
+    """``"kernel"`` where the TPU's block-sparse flash kernel
+    (:mod:`repro.kernels.splash`) computes exactly this attention, else
+    ``"jnp"`` (:func:`flash_attention_jnp`). q_shape (B, Sq, Hq, D),
+    k_shape (B, Sk, Hkv, D); ``arange``: the positions are known statically
+    to be ``arange(S)``, which the kernel derives from its block indices.
+
+    The kernel needs the TPU, causal self-attention with no window, a
+    sequence that its block divides, and no mesh axis that GSPMD would
+    split it over: a compiled kernel cannot be partitioned, so inside a
+    shard_map every axis left to GSPMD must have size 1, and a jit traced
+    outside any mesh must see one device."""
+    S = q_shape[1]
+    blocks = splash.blocks_for(S)
+    if jax.sharding.get_abstract_mesh().empty:
+        split = jax.device_count() > 1
+    else:
+        split = any(n > 1 for n in auto_axes().values())
+    ok = (jax.default_backend() == "tpu" and causal and window == 0
+          and arange and S == k_shape[1] and not split
+          and all(S % b == 0 and b % 128 == 0 for b in blocks))
+    return "kernel" if ok else "jnp"
+
+
+def causal_attention_kernel(q, k, v):
+    """The kernel path of :func:`attention_path`, in
+    :func:`flash_attention_jnp`'s layout: q (B, S, Hq, D), k, v
+    (B, S, Hkv, D), positions ``arange(S)``. The kernel's head-major view
+    is a relabelling: XLA keeps S minor either way."""
+    _PATH_COUNTS["kernel"] += 1
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    scale = q.shape[-1] ** -0.5
+    return t(splash.causal_attention(
+        t(q * scale), t(k), t(v), interpret=jax.default_backend() != "tpu"))
 
 
 def attention_specs(cfg, prefix_layers: Tuple[int, ...] = ()):
@@ -255,16 +300,7 @@ def flash_attention_jnp(q, k, v, *, q_positions, k_positions, causal=True,
     bk = Sk // nblk
     assert Sk % nblk == 0, (Sk, block_k)
 
-    if USE_PALLAS:
-        # Pallas kernels use (B, H, S, D) layout; positions must be the
-        # plain arange the kernels derive from block indices
-        from repro.kernels.flash_attention import flash_attention_trainable
-        out = flash_attention_trainable(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, window=window,
-            block_q=min(block_k, Sq), block_k=bk,
-            interpret=jax.default_backend() != "tpu")
-        return out.transpose(0, 2, 1, 3)
+    _PATH_COUNTS["jnp"] += 1
 
     def prep(q, k, v, k_positions):
         qh = (q * scale).reshape(B, Sq, Hkv, G, D).transpose(0, 2, 3, 1, 4)

@@ -47,18 +47,17 @@ class Model:
 
 
 def _decoder_embed_inputs(params, batch, cfg):
-    """Embed tokens or accept stubbed embeddings; produce positions."""
+    """Embed tokens or accept stubbed embeddings; produce positions:
+    the batch's M-RoPE ids, or None for token batches, whose positions are
+    ``arange(S)`` in every row, known statically (the attention kernel
+    takes only those)."""
     if cfg.frontend == "vision":
         h = batch["embeds"]
         mrope_pos = batch["positions"]  # (3, B, S)
-        B, S = h.shape[0], h.shape[1]
         positions = mrope_pos[0]  # temporal axis doubles as causal order
     else:
-        tokens = batch["tokens"]
-        B, S = tokens.shape
-        h = L.embed_apply(params["embed"], tokens)
-        positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-        mrope_pos = None
+        h = L.embed_apply(params["embed"], batch["tokens"])
+        positions = mrope_pos = None
     return h, positions, mrope_pos
 
 

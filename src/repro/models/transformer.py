@@ -120,14 +120,25 @@ def _rope_qk(q, k, cfg, positions, mrope_pos):
 
 def attn_sublayer(p, h, cfg, *, positions, mrope_pos=None, window=0,
                   causal=True, block_k=1024):
-    """Full-sequence attention (train / prefill). Returns (h', (k, v))."""
+    """Full-sequence attention (train / prefill). Returns (h', (k, v)).
+    ``positions`` (B, S), or None for ``arange(S)`` in every row."""
     x = L.rmsnorm(h, p["norm"], cfg.norm_eps)
+    B, S = h.shape[:2]
+    path = L.attention_path(
+        (B, S, cfg.num_heads, cfg.head_dim),
+        (B, S, cfg.num_kv_heads, cfg.head_dim), causal=causal,
+        window=window, arange=positions is None and mrope_pos is None)
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     q, k, v = _project_qkv(p, x, cfg)
     q, k = _rope_qk(q, k, cfg, positions, mrope_pos)
     with jax.named_scope("attention"):
-        out = L.flash_attention_jnp(q, k, v, q_positions=positions,
-                                    k_positions=positions, causal=causal,
-                                    window=window, block_k=block_k)
+        if path == "kernel":
+            out = L.causal_attention_kernel(q, k, v)
+        else:
+            out = L.flash_attention_jnp(q, k, v, q_positions=positions,
+                                        k_positions=positions, causal=causal,
+                                        window=window, block_k=block_k)
     o = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return h + o, (k, v)
 

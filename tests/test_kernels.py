@@ -204,3 +204,122 @@ class TestFlashBackwardKernels:
                                  return_lse=True, interpret=True)
         assert lse.shape == (B, H, S)
         assert np.all(np.isfinite(np.asarray(lse)))
+
+
+class TestSplashAttention:
+    """The model's attention kernel on the TPU (``repro.kernels.splash``,
+    which ``layers.causal_attention_kernel`` calls), here in interpret
+    mode, against the chunked jnp core and the naive reference; the choice
+    between the two paths (``layers.attention_path``); and its counter."""
+
+    S, D, BLOCKS = 256, 64, (128, 128, 128, 128)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("Hq,Hkv", [(2, 2), (4, 2)])   # MHA, GQA
+    def test_loss_and_grads_match_jnp_and_ref(self, rng, Hq, Hkv, dtype):
+        from repro.kernels import splash
+        from repro.models.layers import flash_attention_jnp
+        B, S, D = 2, self.S, self.D
+        q = jax.random.normal(rng, (B, Hq, S, D)).astype(dtype)
+        k = jax.random.normal(jax.random.fold_in(rng, 1),
+                              (B, Hkv, S, D)).astype(dtype)
+        v = jax.random.normal(jax.random.fold_in(rng, 2),
+                              (B, Hkv, S, D)).astype(dtype)
+        w = jax.random.normal(jax.random.fold_in(rng, 3), (B, Hq, S, D))
+        pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        t = lambda x: x.transpose(0, 2, 1, 3)
+
+        cores = {
+            "kernel": lambda q, k, v: splash.causal_attention(
+                q * D ** -0.5, k, v, blocks=self.BLOCKS, interpret=True),
+            "jnp": lambda q, k, v: t(flash_attention_jnp(
+                t(q), t(k), t(v), q_positions=pos, k_positions=pos,
+                block_k=128)),
+            "ref": KREF.attention_ref,
+        }
+        got = {}
+        for name, core in cores.items():
+            loss = lambda q, k, v: jnp.sum(core(q, k, v).astype(jnp.float32)
+                                           * w)
+            got[name] = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+        (l_k, g_k) = got["kernel"]
+        for g in g_k:   # dq/dk/dv come back in the inputs' dtype
+            assert g.dtype == dtype
+        rtol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        for other in ("jnp", "ref"):
+            l_o, g_o = got[other]
+            assert abs(float(l_k) - float(l_o)) <= rtol * abs(float(l_o)) + \
+                (1.0 if dtype == jnp.bfloat16 else 1e-3), other
+            for a, b, n in zip(g_k, g_o, "qkv"):
+                a = np.asarray(a, np.float32)
+                b = np.asarray(b, np.float32)
+                scale = np.abs(b).max()
+                tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+                assert np.abs(a - b).max() <= tol * scale, (other, n)
+
+    @pytest.mark.parametrize("case,want", [
+        ("tpu", "kernel"),
+        ("cpu backend", "jnp"),
+        ("not causal", "jnp"),
+        ("window", "jnp"),
+        ("Sq != Sk", "jnp"),
+        ("S not a multiple of the block", "jnp"),
+        ("S under 128", "jnp"),
+        ("given positions", "jnp"),
+        ("auto axis of size 2", "jnp"),
+        ("auto axes of size 1", "kernel"),
+        ("no mesh, two devices", "jnp"),
+    ])
+    def test_path_choice(self, monkeypatch, case, want):
+        import contextlib
+        from jax.sharding import AbstractMesh, AxisType
+        from repro.kernels import splash
+        from repro.models.layers import attention_path
+        monkeypatch.setattr(jax, "default_backend",
+                            lambda: "cpu" if case == "cpu backend" else "tpu")
+        if case == "no mesh, two devices":
+            monkeypatch.setattr(jax, "device_count", lambda: 2)
+        S = 2 * max(splash.BLOCKS)
+        S = {"S not a multiple of the block": S + 128,
+             "S under 128": 96}.get(case, S)
+        Sk = S // 2 if case == "Sq != Sk" else S
+        kw = dict(causal=case != "not causal",
+                  window=64 if case == "window" else 0,
+                  arange=case != "given positions")
+        mesh = contextlib.nullcontext()
+        if case.startswith("auto axes") or case.startswith("auto axis"):
+            n = 2 if case == "auto axis of size 2" else 1
+            mesh = jax.sharding.use_abstract_mesh(AbstractMesh(
+                (1, n), ("data", "model"),
+                axis_types=(AxisType.Manual, AxisType.Auto)))
+        with mesh:
+            got = attention_path((2, S, 4, 64), (2, Sk, 2, 64), **kw)
+        assert got == want
+
+    @pytest.mark.parametrize("path", ["jnp", "kernel"])
+    def test_counter_reads_traced_calls_by_path(self, monkeypatch, path):
+        """Tracing a decoder's loss and gradient counts each trace of its
+        scanned block's attention call on the path taken: on the CPU the jnp
+        core; where the choice says kernel, the kernel."""
+        import dataclasses
+        from repro.configs import get_config, reduced
+        from repro.models import build_model
+        from repro.models import layers as L
+        if path == "kernel":
+            monkeypatch.setattr(L, "attention_path",
+                                lambda *a, **k: "kernel")
+        cfg = dataclasses.replace(reduced(get_config("gpt2-medium")),
+                                  num_layers=2)
+        model = build_model(cfg)
+        params = model.abstract_params()
+        tok = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+        before = L.attention_path_counts()
+        jax.eval_shape(jax.value_and_grad(
+            lambda p, b: model.loss_fn(p, b)[0]),
+            params, {"tokens": tok, "labels": tok})
+        after = L.attention_path_counts()
+        other = "jnp" if path == "kernel" else "kernel"
+        assert after[other] == before[other]
+        # the block body traced for the forward, for remat's forward rule,
+        # and for the recompute in its backward
+        assert after[path] - before[path] == 3

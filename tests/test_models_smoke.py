@@ -133,8 +133,11 @@ def test_param_counts_orders_of_magnitude():
 
 
 def test_use_pallas_attention_path_matches_jnp():
-    """models with layers.USE_PALLAS=True (kernel attention) match the
-    pure-jnp flash path — loss and grads (DESIGN.md §8 selectability).
+    """A model whose attention takes the kernel path (the splash kernel,
+    interpret mode here) matches the pure-jnp flash path — loss and grads.
+    On the CPU the model's choice (``layers.attention_path``) is the jnp
+    path, so the test steers it to the kernel; ``tokens`` batches carry the
+    static ``arange`` positions the kernel needs.
 
     Runs in a subprocess: mixing interpret-mode Pallas into a large jit
     program occasionally corrupts the XLA:CPU ORC-JIT state for *later*
@@ -156,16 +159,18 @@ def test_use_pallas_attention_path_matches_jnp():
         cfg = reduced(get_config("granite-8b"))
         m = build_model(cfg)
         params = m.init(jax.random.PRNGKey(0))
-        batch = lm_batch_for(cfg, 1, 32, seed=5)
+        batch = lm_batch_for(cfg, 1, 128, seed=5)
 
         def loss_and_grad():
             (l, _), g = jax.value_and_grad(
-                lambda p: m.loss_fn(p, batch, block_k=16), has_aux=True)(params)
+                lambda p: m.loss_fn(p, batch), has_aux=True)(params)
             return float(l), g
 
         l_jnp, g_jnp = loss_and_grad()
-        L.USE_PALLAS = True
+        assert L.attention_path_counts()["kernel"] == 0
+        L.attention_path = lambda *a, **k: "kernel"
         l_pal, g_pal = loss_and_grad()
+        assert L.attention_path_counts()["kernel"] > 0
         assert abs(l_jnp - l_pal) < 1e-4, (l_jnp, l_pal)
         for a, b in zip(jax.tree.leaves(g_jnp), jax.tree.leaves(g_pal)):
             np.testing.assert_allclose(np.asarray(a, np.float32),

@@ -98,6 +98,51 @@ def test_flash_attention_compiles(one_chip):
     _compile(ops.flash_attention, q, q, q, causal=True, interpret=False)
 
 
+# each cell's attention core: gpt2-medium (batch 8, 16 heads, seq 1024,
+# head_dim 64, f32) and stablelm-1.6b (batch 2, 32 heads, seq 4096, bf16)
+ATTENTION = {"gpt2-medium": ((8, 16, 1024, 64), jnp.float32),
+             "stablelm-1.6b": ((2, 32, 4096, 64), jnp.bfloat16)}
+
+
+def _splash_loss(q, k, v):
+    from repro.kernels import splash
+    return jnp.sum(splash.causal_attention(q, k, v).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("pass_", ["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("cell", list(ATTENTION))
+def test_splash_attention_compiles(one_chip, cell, pass_):
+    """The model's attention kernel, forward alone and under ``jax.grad``
+    (the fused backward), at each cell's shape and dtype."""
+    shape, dt = ATTENTION[cell]
+    q = _sds(shape, dt, one_chip)
+    fn = _splash_loss if pass_ == "fwd" else jax.grad(_splash_loss, (0, 1, 2))
+    _compile(jax.jit(fn), q, q, q)
+
+
+def test_decoder_grad_takes_attention_kernel_on_tpu(one_chip, monkeypatch):
+    """``value_and_grad`` of a 2-layer decoder at gpt2-medium width: on the
+    TPU the model's own choice (``layers.attention_path``) takes the kernel.
+    The backend is the CPU's while tracing here, so the test steers it."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models import layers as L
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("gpt2-medium"), num_layers=2)
+    model = build_model(cfg)
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          model.abstract_params())
+    tok = _sds((2, 1024), jnp.int32, one_chip)
+    before = L.attention_path_counts()
+    fn = jax.jit(jax.value_and_grad(
+        lambda p, b: model.loss_fn(p, b)[0]))
+    _compile(fn, params, {"tokens": tok, "labels": tok})
+    after = L.attention_path_counts()
+    assert after["kernel"] > before["kernel"]
+    assert after["jnp"] == before["jnp"]
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     import numpy as np
@@ -141,3 +186,21 @@ def test_pallas_gossip_lanes_compile_in_worker_body(four_chips, lane):
     _compile(hop, _sds((M,), jnp.float32, wsh),
              _sds((), jnp.int32, NamedSharding(four_chips, P())),
              *([plane] * n_planes))
+
+
+def test_splash_attention_compiles_in_worker_body(four_chips):
+    """The attention kernel inside the shard_map body that is manual over
+    the workers and leaves 'model' (size 1) to GSPMD, as the LayUp lanes
+    run the model: ``per_shard`` makes the call manual over 'model'."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.train import shard_map
+    shape, dt = ATTENTION["gpt2-medium"]
+
+    def body(q, k, v):
+        return jax.grad(_splash_loss, (0, 1, 2))(q, k, v)
+
+    wsh = NamedSharding(four_chips, P("data"))
+    q = _sds((4,) + shape[1:], dt, wsh)
+    fn = jax.jit(shard_map(body, mesh=four_chips, in_specs=P("data"),
+                           out_specs=P("data"), axis_names={"data"}))
+    _compile(fn, q, q, q)
