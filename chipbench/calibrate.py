@@ -70,7 +70,7 @@ def main(argv=None) -> int:
     R.device_gate(chips)
     R.enable_cache()
     import jax
-    ref = R._family_module("reference", cdict)
+    ref = cells.family_module("reference", cdict)
     cfg = cells.load_config(cell["config"])
     devices = jax.devices()[:chips]
     mesh = jax.make_mesh(tuple(job["mesh"]), ("data", "model"),

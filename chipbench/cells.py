@@ -7,6 +7,13 @@ Every configuration is ``configs/<name>.json``, every traffic mix
 all under this directory; ``BENCHMARK.json`` at the root of the repo names
 them. A new cell adds files and one entry there, and edits nothing else.
 
+A configuration file states what it is (``RECORD_KEYS``) and the sizes
+that its family's reference (``reference/<family>.py``) reads, listed in
+that module's ``SIZES``; each of them is a field of the program's
+``ModelConfig`` and overrides the registry's value. A new family brings
+its reference, with its ``SIZES``, and its ``flops/<family>.py``, and
+edits no file here.
+
 A traffic file holds the job's sizes (``JOB_KEYS``) and, under ``step``,
 the keyword arguments of ``repro.launch.train.make_step``, passed to it
 as they stand: ``algo`` and the schedule's ``fb_ratio``, ``update_delay``
@@ -16,6 +23,8 @@ and ``shifts``, and any engine or kernel choice (``overlap``,
 """
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import inspect
 import json
 import os
@@ -26,13 +35,10 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-# the sizes every configuration file states; they override the registry's
-SIZE_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
-             "head_dim", "d_ff", "vocab_size", "tie_embeddings",
-             "rope_fraction", "rope_theta", "norm_eps")
-# what else a configuration file may hold: what it is, and its records
-CONFIG_KEYS = SIZE_KEYS + ("name", "registry", "family", "source",
-                           "deployment", "dtype", "reduced", "assumed")
+# what a configuration file says it is, and its records; its sizes are
+# those its family's reference reads
+RECORD_KEYS = ("name", "registry", "family", "source", "deployment", "dtype",
+               "reduced", "assumed")
 # what a traffic file holds besides ``step``
 JOB_KEYS = ("workers", "mesh", "batch_per_worker", "seq_len", "optimizer",
             "lr", "step", "why")
@@ -58,11 +64,35 @@ def find_cell(bench: Dict[str, Any], workload: str) -> Dict[str, Any]:
     raise SystemExit(f"chipbench: no workload {workload!r} in BENCHMARK.json")
 
 
+def family_module(kind: str, cdict: Dict[str, Any]):
+    """``chipbench/<kind>/<family>.py`` (``reference``, ``flops``), chosen
+    by the configuration's family."""
+    return importlib.import_module(f"chipbench.{kind}.{cdict['family']}")
+
+
 def config_dict(name: str, base: Optional[str] = None) -> Dict[str, Any]:
+    """The configuration file, refused unless it states every size its
+    family's reference reads, each one a size the program has, and
+    nothing else but its records."""
+    from repro.configs import ModelConfig
     d = _read("configs", name, base)
-    unknown = sorted(set(d) - set(CONFIG_KEYS))
+    sizes = family_module("reference", d).SIZES
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    family = d["family"]
+    no_field = sorted(set(sizes) - fields)
+    if no_field:
+        raise ValueError(f"the {family} reference reads {no_field}, which "
+                         "the program has no size for")
+    unknown = sorted(set(d) - set(RECORD_KEYS) - set(sizes))
     if unknown:
-        raise ValueError(f"config {name}: nothing reads {unknown}")
+        why = "; ".join(
+            f"{k}: the {family} reference does not read it" if k in fields
+            else f"{k}: the program has no such size" for k in unknown)
+        raise ValueError(f"config {name}: nothing reads {unknown} ({why})")
+    missing = [k for k in sizes if k not in d]
+    if missing:
+        raise ValueError(f"config {name} does not state {missing}, which "
+                         f"the {family} reference reads")
     return d
 
 
@@ -90,11 +120,9 @@ def load_config(name: str, base: Optional[str] = None, **override):
     from repro.configs import get_config
     import jax.numpy as jnp
     d = dict(config_dict(name, base), **override)
-    missing = [k for k in SIZE_KEYS if k not in d]
-    if missing:
-        raise ValueError(f"config {name} does not state {missing}")
+    sizes = family_module("reference", d).SIZES
     return get_config(d["registry"]).with_(
-        dtype=jnp.dtype(d["dtype"]), **{k: d[k] for k in SIZE_KEYS})
+        dtype=jnp.dtype(d["dtype"]), **{k: d[k] for k in sizes})
 
 
 def seed_key(seed: int) -> int:

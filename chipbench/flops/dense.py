@@ -6,11 +6,15 @@ attention products QK^T and PV over the causal half of the score matrix
 (S^2/2 pairs per sequence, not the S^2 a kernel may compute). Embedding
 lookups, norms, softmax and the optimizer are not matrix products and
 are left out. Recomputation (remat) is not counted: this is what the
-algorithm needs, not what runs.
+algorithm needs, not what runs. The attention core's least HBM bytes are
+counted too, for the kernel that computes it (``chipbench/kernels``).
 """
 from __future__ import annotations
 
 from typing import Any, Dict
+
+# bytes of one element of each dtype a configuration may state
+ITEMSIZE = {"float32": 4, "bfloat16": 2}
 
 
 def params(c: Dict[str, Any]) -> int:
@@ -37,8 +41,10 @@ def forward_flops_per_token(c: Dict[str, Any], seq: int) -> Dict[str, float]:
             "head": float(head)}
 
 
-def step_flops(c: Dict[str, Any], job: Dict[str, Any]) -> float:
-    """Matmul operations of one training step over all workers.
+def step_flops_by_part(c: Dict[str, Any],
+                       job: Dict[str, Any]) -> Dict[str, float]:
+    """Matmul operations of one training step over all workers, by part
+    of the model (``dense``, ``attention``, ``head``).
 
     LayUp: the forward over every token of the worker's rows and the
     backward (twice the forward) over the backward slice, 1/R of them;
@@ -46,13 +52,38 @@ def step_flops(c: Dict[str, Any], job: Dict[str, Any]) -> float:
     over every token."""
     S = int(job["seq_len"])
     tokens = int(job["batch_per_worker"]) * S
-    fwd = sum(forward_flops_per_token(c, S).values())
     if job["step"]["algo"] == "layup":
-        per_worker = (fwd * tokens
-                      + 2 * fwd * tokens / int(job["step"]["fb_ratio"]))
+        passes = tokens + 2 * tokens / int(job["step"]["fb_ratio"])
     else:
-        per_worker = 3 * fwd * tokens
-    return per_worker * int(job["workers"])
+        passes = 3 * tokens
+    return {part: f * passes * int(job["workers"])
+            for part, f in forward_flops_per_token(c, S).items()}
+
+
+def step_bytes_by_part(c: Dict[str, Any],
+                       job: Dict[str, Any]) -> Dict[str, float]:
+    """The least HBM bytes of one training step over all workers, for the
+    parts a kernel computes that may be bound by bytes: the attention
+    core, whose forward reads q, k and v and writes o, and whose backward
+    reads q, k, v, o and o's gradient and writes the gradients of q, k and
+    v, each once, in the configuration's dtype (softmax statistics left
+    out). The dense projections and the head are bound by operations."""
+    S = int(job["seq_len"])
+    tokens = int(job["batch_per_worker"]) * S
+    bwd = (tokens / int(job["step"]["fb_ratio"])
+           if job["step"]["algo"] == "layup" else tokens)
+    item = ITEMSIZE[c["dtype"]]
+    q = c["num_heads"] * c["head_dim"]
+    kv = c["num_kv_heads"] * c["head_dim"]
+    per_layer = tokens * (2 * q + 2 * kv) + bwd * (4 * q + 4 * kv)
+    return {"attention": float(c["num_layers"] * per_layer * item
+                               * int(job["workers"]))}
+
+
+def step_flops(c: Dict[str, Any], job: Dict[str, Any]) -> float:
+    """Matmul operations of one training step over all workers: the sum of
+    :func:`step_flops_by_part`."""
+    return sum(step_flops_by_part(c, job).values())
 
 
 def trained_tokens_per_step(job: Dict[str, Any]) -> int:

@@ -57,6 +57,12 @@ FP8 = Precision("fp8", quantize="fp8", matmul="default")
 # the control of each stated precision: the nearest one below it
 CONTROLS = {"float32": BF16, "bfloat16": FP8}
 
+# the sizes this reference reads: a configuration file of the family states
+# each one, and each is a size the program runs (``chipbench/cells.py``)
+SIZES = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+         "d_ff", "vocab_size", "tie_embeddings", "rope_fraction",
+         "rope_theta", "norm_eps")
+
 # the step arguments the reference follows, and those that only choose how
 # the same step runs (its engine, streams and kernels)
 MODELED = {"layup": ("algo", "fb_ratio", "update_delay", "shifts"),

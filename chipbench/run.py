@@ -16,7 +16,8 @@ is that comparison (``chipbench/check.py``).
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` the window is traced and the metrics are the per-layer
-ones, each read by ``chipbench/metrics/<name>.py``.
+ones, each read by ``chipbench/metrics/<name>.py`` from the window's trace
+with the program's scopes (``chipbench/stages.py``).
 
 The last line of stdout is one JSON object. Without a TPU, or with fewer
 chips than the cell asks for, the run prints no result and exits 2.
@@ -30,7 +31,6 @@ T_START = time.perf_counter()
 import argparse
 import collections
 import gc
-import importlib
 import importlib.util
 import json
 import math
@@ -103,11 +103,6 @@ def load_reader(name: str):
     return mod.read
 
 
-def _family_module(kind: str, cdict):
-    """``chipbench/<kind>/<family>.py``, chosen by the config's family."""
-    return importlib.import_module(f"chipbench.{kind}.{cdict['family']}")
-
-
 def checked_steps(prog, job, V: int, seed: int, n: int) -> dict:
     """The program's first ``n`` steps through the window's own call and
     feed: each loss, the optimizer's momentum norms after the first step
@@ -142,7 +137,7 @@ def run(args, *, base: str = HERE, bench_path=None, gate=device_gate,
     job = cells.load_traffic(cell["traffic"], base)
     cdict = cells.config_dict(cell["config"], base)
     limits = cells.load_limits(cell["name"], base)
-    ref = _family_module("reference", cdict)
+    ref = cells.family_module("reference", cdict)
     ref.check_job(job)
     chips = int(cell["chips"])
     if int(job["workers"]) != chips or math.prod(job["mesh"]) != chips:
@@ -155,7 +150,7 @@ def run(args, *, base: str = HERE, bench_path=None, gate=device_gate,
         enable_cache()
     import jax
 
-    flops = _family_module("flops", cdict)
+    flops = cells.family_module("flops", cdict)
     cfg = cells.load_config(cell["config"], base)
     devices = jax.devices()[:chips]
     mesh = jax.make_mesh(tuple(job["mesh"]), ("data", "model"),
@@ -227,10 +222,12 @@ def run(args, *, base: str = HERE, bench_path=None, gate=device_gate,
     breakdown = None
     dev = dict(device, memory_peak_bytes=int(peak))
     if args.trace:
+        from chipbench import stages
         from chipbench import trace as T
-        tr = T.load(trace_dir)
+        xplane = T.find_xplane(trace_dir)
+        tr = stages.load(xplane)
         if keep_trace:
-            shutil.copy(T.find_xplane(trace_dir), keep_trace)
+            shutil.copy(xplane, keep_trace)
         shutil.rmtree(trace_dir, ignore_errors=True)
         a, z = T.window_of(tr)
         dev["busy_s"] = (sum(T.busy(tr, c) for c in tr.chips())
@@ -238,8 +235,11 @@ def run(args, *, base: str = HERE, bench_path=None, gate=device_gate,
         dev["window_s"] = z - a
         # what the per-layer readers read
         rd = types.SimpleNamespace(
-            trace=tr, steps=steps, window_s=window_s, chips=chips,
-            peak=peaks, flops_per_step=flops_step,
+            trace=tr, stages=stages.per_step(tr, steps), steps=steps,
+            window_s=window_s, chips=chips, peak=peaks,
+            flops_per_step=flops_step,
+            flops_by_part=flops.step_flops_by_part(cdict, job),
+            bytes_by_part=flops.step_bytes_by_part(cdict, job),
             compiled_bytes=compiled_bytes, job=job, config=cdict)
         for m in bench["per_layer"]:
             if cell["name"] not in m.get("workloads", [cell["name"]]):

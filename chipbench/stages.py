@@ -218,20 +218,20 @@ def top_ops(tr: ScopedTrace, stage: str,
 
 def per_step(tr: ScopedTrace, steps: int) -> Dict[str, Optional[float]]:
     """Each per-stage number in ms per step, at the chip where it is
-    largest; ``None`` where the trace has nothing of it to read (no
-    ``mix`` op, no program span)."""
+    largest; ``None`` where the trace has nothing of it to read (a stage
+    or cross-cut with no op, such as ``mix`` on one worker, or no program
+    span)."""
     if not tr.ops or not steps:
         return {}
     chips = tr.chips()
     parts = {c: partition(tr, c) for c in chips}
     ms = lambda v: 1e3 * v / steps
-    out = {f"{s}_ms_per_step": ms(max(parts[c][s] for c in chips))
+    some = lambda v: ms(v) if v > 0 else None
+    out = {f"{s}_ms_per_step": some(max(parts[c][s] for c in chips))
            for s in STAGES}
-    if out["mix_ms_per_step"] <= 0:
-        out["mix_ms_per_step"] = None
     for name in CROSS_CUTS:
-        out[f"{name}_ms_per_step"] = ms(max(cross_cut(tr, c, name)
-                                            for c in chips))
+        out[f"{name}_ms_per_step"] = some(max(cross_cut(tr, c, name)
+                                              for c in chips))
     out["dispatch_idle_ms_per_step"] = (
         ms(max(dispatch_idle(tr, c) for c in chips))
         if tr.program_spans else None)

@@ -43,12 +43,13 @@ def test_every_cell_names_existing_files(bench):
 
 def test_every_cell_is_read_whole(bench):
     """Every key of each cell's files is read: the traffic's job and step
-    by the harness, ``make_step`` and the reference; the configuration's
-    by the harness."""
+    by the harness, ``make_step`` and the cell's family reference, as
+    ``chipbench/run.py`` takes it; the configuration's by the harness and
+    that reference."""
     from chipbench import cells
-    from chipbench.reference import dense
     for w in bench["workloads"]:
-        dense.check_job(cells.load_traffic(w["traffic"]))
+        ref = cells.family_module("reference", cells.config_dict(w["config"]))
+        ref.check_job(cells.load_traffic(w["traffic"]))
         cells.load_config(w["config"])
 
 

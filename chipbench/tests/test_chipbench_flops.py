@@ -60,3 +60,35 @@ def test_untied_head_adds_its_matrix():
     c = _load(CONFIGS, "stablelm-1.6b")
     tied = dict(c, tie_embeddings=True)
     assert dense.params(c) - dense.params(tied) == 2048 * 100352
+
+
+@pytest.mark.parametrize("config,traffic,share", [
+    ("gpt2-medium", "layup-r2d1.m1.b8s1024", 0.0525),
+    ("gpt2-medium", "layup-r2d1.m4.b8s1024", 0.0525),
+    ("gpt2-medium", "ddp.m1.b8s1024", 0.0525),
+    ("stablelm-1.6b", "layup-r2d1.m1.b2s4096", 0.0890),
+])
+def test_step_flops_by_part_sum_to_step_flops(config, traffic, share):
+    c, job = _load(CONFIGS, config), _load(TRAFFIC, traffic)
+    parts = dense.step_flops_by_part(c, job)
+    assert set(parts) == {"dense", "attention", "head"}
+    assert sum(parts.values()) == pytest.approx(dense.step_flops(c, job),
+                                                rel=1e-15)
+    assert parts["attention"] / sum(parts.values()) == pytest.approx(
+        share, abs=5e-4)
+
+
+@pytest.mark.parametrize("config,traffic,want", [
+    # q, k, v read and o written over the 8192 forward tokens, q, k, v, o
+    # and do read and dq, dk, dv written over the 4096 backward tokens;
+    # 1024 wide each, f32, 24 layers
+    ("gpt2-medium", "layup-r2d1.m1.b8s1024",
+     24 * 4 * 1024 * (8192 * 4 + 4096 * 8)),
+    ("gpt2-medium", "ddp.m1.b8s1024", 24 * 4 * 1024 * 8192 * 12),
+    # 2048 wide, bf16, 6 layers
+    ("stablelm-1.6b", "layup-r2d1.m1.b2s4096",
+     6 * 2 * 2048 * (8192 * 4 + 4096 * 8)),
+])
+def test_attention_bytes_by_hand(config, traffic, want):
+    c, job = _load(CONFIGS, config), _load(TRAFFIC, traffic)
+    assert dense.step_bytes_by_part(c, job) == {"attention": want}
