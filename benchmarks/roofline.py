@@ -19,7 +19,7 @@ SHAPE_ORDER = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
 ARCH_ORDER = [
     "jamba-v0.1-52b", "qwen2-vl-2b", "mamba2-780m", "mixtral-8x7b",
     "granite-8b", "qwen3-moe-30b-a3b", "yi-34b", "stablelm-1.6b",
-    "moonshot-v1-16b-a3b", "whisper-large-v3",
+    "moonlight-16b-a3b", "whisper-large-v3",
 ]
 
 

@@ -1,0 +1,15 @@
+"""Device time of latent attention's kv path (the ``latent`` scope: the kv
+down-projection, the latent's norm, its expansion to keys and values, and
+the rotary split and concatenation), across forward, backward and
+recompute. In ms per step, at the chip where it is largest, read from the
+window's trace by the program's scopes (``chipbench/stages.py``). ``None``
+where the trace holds no op under the scope."""
+from chipbench import stages
+
+
+def read(run):
+    tr = run.trace
+    if tr is None or not tr.ops or not run.steps:
+        return None
+    t = max(stages.cross_cut(tr, c, "latent") for c in tr.chips())
+    return 1e3 * t / run.steps if t > 0 else None

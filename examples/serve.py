@@ -7,7 +7,7 @@ Known --arch values (REDUCED variants on the CPU container; full configs
 are exercised via the dry-runs):
 
     decoder LMs : gpt2-medium, gpt2-xl, granite-8b, stablelm-1.6b, yi-34b
-    MoE         : mixtral-8x7b, moonshot-v1-16b-a3b, qwen3-moe-30b-a3b
+    MoE         : mixtral-8x7b, moonlight-16b-a3b, qwen3-moe-30b-a3b
     SSM / hybrid: mamba2-780m, jamba-v0.1-52b
     multimodal  : qwen2-vl-2b, whisper-large-v3  (need modality inputs —
                   not servable by this text-only demo loop)

@@ -38,8 +38,21 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0  # per-expert hidden dim (0 -> d_ff)
     moe_layer_period: int = 1  # MoE every k-th layer (jamba: 2)
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
+    capacity_factor: float = 1.25  # the dry-run's grouped dispatch only
+    router_aux_weight: float = 0.01  # the balance loss's weight (alpha)
+    # DeepSeek-V3-style experts, under the names of the published config.json
+    n_shared_experts: int = 0  # always-on experts: one SwiGLU of n x moe_d_ff
+    first_k_dense_replace: int = 0  # leading dense layers (MLP width d_ff)
+    scoring_func: str = "softmax"  # router scores: softmax | sigmoid
+    topk_method: str = "greedy"  # greedy | noaux_tc (select on scores + bias)
+    norm_topk_prob: bool = True  # the chosen scores normalised to sum 1
+    routed_scaling_factor: float = 1.0  # gates scaled after normalising
+    n_group: int = 1  # group-limited routing: only n_group == topk_group
+    topk_group: int = 1
+    seq_aux: bool = False  # balance loss per sequence, not per batch
+    # chips sharing each expert layer; this one holds experts
+    # [0, num_experts // ep_size) and routes over all of them
+    ep_size: int = 1
 
     # SSM (mamba2-style SSD)
     ssm_state: int = 0
@@ -47,6 +60,15 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     attn_layer_period: int = 0  # hybrid: attention every k-th layer (jamba: 8)
+
+    # multi-head latent attention (DeepSeek-V2/V3) when kv_lora_rank > 0:
+    # keys and values expanded from a normed latent of kv_lora_rank, q·k
+    # over qk_nope_head_dim + qk_rope_head_dim, values of v_head_dim
+    kv_lora_rank: int = 0
+    q_lora_rank: Optional[int] = None  # a compressed q: not built
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # attention details
     sliding_window: int = 0  # 0 -> full attention
@@ -69,9 +91,46 @@ class ModelConfig:
 
     source: str = ""  # citation
 
+    # published config.json keys of which one value is built
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    num_nextn_predict_layers: int = 0
+    max_position_embeddings: int = 0  # 0: not stated
+    model_type: str = ""
+
+    # a published config.json's names for the sizes above (HF_NAMES); each
+    # is taken in place of its own field's value and reads None after
+    hidden_size: Optional[int] = None
+    num_hidden_layers: Optional[int] = None
+    num_attention_heads: Optional[int] = None
+    num_key_value_heads: Optional[int] = None
+    intermediate_size: Optional[int] = None
+    n_routed_experts: Optional[int] = None
+    num_experts_per_tok: Optional[int] = None
+    moe_intermediate_size: Optional[int] = None
+    moe_layer_freq: Optional[int] = None
+    rms_norm_eps: Optional[float] = None
+    tie_word_embeddings: Optional[bool] = None
+
     def __post_init__(self):
+        for alias, name in HF_NAMES.items():
+            v = getattr(self, alias)
+            if v is not None:
+                object.__setattr__(self, name, v)
+                object.__setattr__(self, alias, None)
         if self.head_dim == 0 and self.num_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        unbuilt = {"attention_bias": self.attention_bias,
+                   "hidden_act": self.hidden_act != "silu",
+                   "q_lora_rank": self.q_lora_rank is not None,
+                   "num_nextn_predict_layers": self.num_nextn_predict_layers,
+                   "topk_group": self.topk_group != self.n_group,
+                   "ep_size": self.ep_size < 1 or (
+                       self.num_experts % self.ep_size != 0)}
+        bad = sorted(k for k, v in unbuilt.items() if v)
+        if bad:
+            raise ValueError(f"{self.name}: no model is built for the "
+                             f"stated {bad}")
 
     # ---- derived quantities -------------------------------------------------
     @property
@@ -101,12 +160,22 @@ class ModelConfig:
         return (l % self.attn_layer_period) == self.attn_layer_period // 2
 
     def is_moe_layer(self, l: int) -> bool:
-        if self.num_experts == 0:
+        if self.num_experts == 0 or l < self.first_k_dense_replace:
             return False
+        l -= self.first_k_dense_replace
         return (l % self.moe_layer_period) == self.moe_layer_period - 1
 
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this chip holds: ``[0, experts_held)``."""
+        return self.num_experts // self.ep_size
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -118,6 +187,12 @@ class ModelConfig:
         embed = V * d * (1 if self.tie_embeddings else 2)
 
         def attn_params():
+            if self.mla:
+                H, r = self.num_heads, self.kv_lora_rank
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                return (d * H * qk + d * (r + self.qk_rope_head_dim) + r
+                        + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                        + H * self.v_head_dim * d)
             return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
         def dense_mlp():
@@ -125,6 +200,7 @@ class ModelConfig:
 
         def moe_mlp(active: bool):
             e = self.experts_per_token if active else self.num_experts
+            e += self.n_shared_experts
             # experts (swiglu) + router
             return 3 * d * self.expert_d_ff() * e + d * self.num_experts
 
@@ -152,6 +228,17 @@ class ModelConfig:
                 total += attn_params() + dense_mlp()
                 active += attn_params() + dense_mlp()
         return {"total": float(total), "active": float(active)}
+
+
+# a published Hugging Face config.json's key → the field it sets
+HF_NAMES = {
+    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "intermediate_size": "d_ff", "n_routed_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "moe_d_ff", "moe_layer_freq": "moe_layer_period",
+    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
+}
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +289,7 @@ def list_configs():
 _ARCH_MODULES = [
     "jamba_v0_1_52b", "qwen2_vl_2b", "mamba2_780m", "mixtral_8x7b",
     "granite_8b", "qwen3_moe_30b_a3b", "yi_34b", "stablelm_1_6b",
-    "moonshot_v1_16b_a3b", "whisper_large_v3", "gpt2_medium", "gpt2_xl",
+    "moonlight_16b_a3b", "whisper_large_v3", "gpt2_medium", "gpt2_xl",
 ]
 
 _LOADED = False
@@ -239,7 +326,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     if cfg.num_experts:
         kw.update(num_experts=4,
                   experts_per_token=min(cfg.experts_per_token, 2),
-                  moe_d_ff=128)
+                  moe_d_ff=128, ep_size=1)
+    if cfg.mla:
+        kw.update(kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                  v_head_dim=32)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=32)
     if cfg.attn_layer_period:

@@ -40,8 +40,10 @@ def blocks_for(seq_len: int,
 
 def causal_attention(q, k, v, *, blocks: Tuple[int, ...] = BLOCKS,
                      interpret: bool = False):
-    """q: (B, Hq, S, D), already scaled by ``D**-0.5``; k, v: (B, Hkv, S, D)
-    with Hkv dividing Hq → (B, Hq, S, D) in q's dtype. Every block of
+    """q: (B, Hq, S, D), already scaled; k: (B, Hkv, S, D), v: (B, Hkv, S,
+    Dv), with Hkv dividing Hq → (B, Hq, S, Dv) in q's dtype (the kernel
+    takes the value width on its own: latent attention's q·k at 192 and
+    values at 128 need no padding). Every block of
     :func:`blocks_for` must divide S. Made manual over the mesh axes the
     caller leaves to GSPMD (:func:`~repro.kernels.ops.per_shard`)."""
     S = q.shape[2]

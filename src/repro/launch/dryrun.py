@@ -34,7 +34,7 @@ from repro.models import build_model
 ASSIGNED = [
     "jamba-v0.1-52b", "qwen2-vl-2b", "mamba2-780m", "mixtral-8x7b",
     "granite-8b", "qwen3-moe-30b-a3b", "yi-34b", "stablelm-1.6b",
-    "moonshot-v1-16b-a3b", "whisper-large-v3",
+    "moonlight-16b-a3b", "whisper-large-v3",
 ]
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
@@ -117,7 +117,8 @@ def run_one(arch: str, shape_name: str, *, algo: str = "layup",
     t_full = time.time() - t0
 
     from repro.models.transformer import _superblock_period
-    n_super = cfg.num_layers // _superblock_period(cfg)
+    n_super = ((cfg.num_layers - cfg.first_k_dense_replace)
+               // _superblock_period(cfg))
     from repro.launch.mesh import num_workers as _nw
     n_workers = _nw(mesh)
     n_model = mesh.size // n_workers

@@ -205,8 +205,8 @@ def attention_path(q_shape, k_shape, *, causal: bool, window: int,
 
 def causal_attention_kernel(q, k, v):
     """The kernel path of :func:`attention_path`, in
-    :func:`flash_attention_jnp`'s layout: q (B, S, Hq, D), k, v
-    (B, S, Hkv, D), positions ``arange(S)``. The kernel's head-major view
+    :func:`flash_attention_jnp`'s layout: q, k (B, S, Hq|Hkv, D), v
+    (B, S, Hkv, Dv), positions ``arange(S)``. The kernel's head-major view
     is a relabelling: XLA keeps S minor either way."""
     _PATH_COUNTS["kernel"] += 1
     t = lambda x: x.transpose(0, 2, 1, 3)
@@ -217,6 +217,8 @@ def causal_attention_kernel(q, k, v):
 
 def attention_specs(cfg, prefix_layers: Tuple[int, ...] = ()):
     """Projection specs for one attention sub-layer (optionally stacked)."""
+    if cfg.mla:
+        return latent_attention_specs(cfg, prefix_layers)
     d, hq, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     L = prefix_layers
     La = tuple("layers" for _ in L)
@@ -232,6 +234,30 @@ def attention_specs(cfg, prefix_layers: Tuple[int, ...] = ()):
         out["q_norm"] = ParamSpec(L + (hd,), La + ("hd",), init="ones")
         out["k_norm"] = ParamSpec(L + (hd,), La + ("hd",), init="ones")
     return out
+
+
+def latent_attention_specs(cfg, prefix_layers: Tuple[int, ...] = ()):
+    """Multi-head latent attention without q compression (DeepSeek-V2/V3):
+    ``wq`` to every head's q·k width, ``wkv_a`` to the kv latent and the
+    one rotary key head, ``kv_norm`` on the latent, ``wkv_b`` from it to
+    each head's key and value, ``wo`` from the values."""
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    L = prefix_layers
+    La = tuple("layers" for _ in L)
+    sc = 0.02
+    return {
+        "wq": ParamSpec(L + (d, H, nope + rope), La + ("embed", "heads", "hd"),
+                        scale=sc),
+        "wkv_a": ParamSpec(L + (d, r + rope), La + ("embed", None), scale=sc),
+        "kv_norm": ParamSpec(L + (r,), La + (None,), init="ones"),
+        "wkv_b": ParamSpec(L + (r, H, nope + dv), La + (None, "heads", "hd"),
+                           scale=sc),
+        "wo": ParamSpec(L + (H, dv, d), La + ("heads", "hd", "embed"),
+                        init="scaled",
+                        scale=sc / np.sqrt(max(2 * cfg.num_layers, 1))),
+    }
 
 
 def _gqa_scores(q, k):
@@ -255,10 +281,11 @@ def _block_mask(kp, q_positions, causal, window):
 
 
 def _flash_fwd(qh, kb, vb, kpos, q_positions, causal, window):
-    """qh: (B,Hkv,G,Sq,D) pre-scaled; kb/vb: (nblk,B,Hkv,bk,D);
-    kpos: (nblk,B,bk). Returns (out_unnormalized→normalized, lse)."""
-    B, Hkv, G, Sq, D = qh.shape
-    acc0 = jnp.zeros((B, Hkv, G, Sq, D), jnp.float32)
+    """qh: (B,Hkv,G,Sq,D) pre-scaled; kb: (nblk,B,Hkv,bk,D); vb:
+    (nblk,B,Hkv,bk,Dv); kpos: (nblk,B,bk). Returns (out_unnormalized→
+    normalized, lse)."""
+    B, Hkv, G, Sq, _ = qh.shape
+    acc0 = jnp.zeros((B, Hkv, G, Sq, vb.shape[-1]), jnp.float32)
     m0 = jnp.full((B, Hkv, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hkv, G, Sq), jnp.float32)
 
@@ -289,11 +316,12 @@ def flash_attention_jnp(q, k, v, *, q_positions, k_positions, causal=True,
     O(Sq·Sk) probability tensor (saves only out + logsumexp). This is the
     pure-jnp reference the Pallas kernel is validated against.
 
-    q: (B, Sq, Hq, D);  k, v: (B, Sk, Hkv, D).
+    q: (B, Sq, Hq, D);  k: (B, Sk, Hkv, D);  v: (B, Sk, Hkv, Dv).
     positions: (B, Sq) / (B, Sk) absolute token indices (negative = invalid).
     """
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     G = Hq // Hkv
     scale = D ** -0.5
     nblk = max(Sk // block_k, 1)
@@ -307,7 +335,7 @@ def flash_attention_jnp(q, k, v, *, q_positions, k_positions, causal=True,
         kb = (k.transpose(0, 2, 1, 3)
               .reshape(B, Hkv, nblk, bk, D).transpose(2, 0, 1, 3, 4))
         vb = (v.transpose(0, 2, 1, 3)
-              .reshape(B, Hkv, nblk, bk, D).transpose(2, 0, 1, 3, 4))
+              .reshape(B, Hkv, nblk, bk, Dv).transpose(2, 0, 1, 3, 4))
         kpos = k_positions.reshape(B, nblk, bk).transpose(1, 0, 2)
         return qh, kb, vb, kpos
 
@@ -315,18 +343,18 @@ def flash_attention_jnp(q, k, v, *, q_positions, k_positions, causal=True,
     def run(q, k, v, q_pos, k_pos):
         qh, kb, vb, kpos = prep(q, k, v, k_pos)
         out, _ = _flash_fwd(qh, kb, vb, kpos, q_pos, causal, window)
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, D).astype(q.dtype)
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, Dv).astype(q.dtype)
 
     def run_fwd(q, k, v, q_pos, k_pos):
         qh, kb, vb, kpos = prep(q, k, v, k_pos)
         out, lse = _flash_fwd(qh, kb, vb, kpos, q_pos, causal, window)
-        o = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, D).astype(q.dtype)
+        o = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, Dv).astype(q.dtype)
         return o, (q, k, v, q_pos, k_pos, out, lse)
 
     def run_bwd(res, do):
         q, k, v, q_pos, k_pos, out, lse = res
         qh, kb, vb, kpos = prep(q, k, v, k_pos)
-        doh = (do.reshape(B, Sq, Hkv, G, D).transpose(0, 2, 3, 1, 4)
+        doh = (do.reshape(B, Sq, Hkv, G, Dv).transpose(0, 2, 3, 1, 4)
                .astype(jnp.float32))
         delta = jnp.sum(doh * out, axis=-1)  # (B,Hkv,G,Sq)
 
@@ -354,7 +382,7 @@ def flash_attention_jnp(q, k, v, *, q_positions, k_positions, causal=True,
         dq_acc, (dkb, dvb) = jax.lax.scan(body, dq0, (kb, vb, kpos))
         dq = (dq_acc * scale).transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, D)
         dk = (dkb.transpose(1, 0, 3, 2, 4).reshape(B, Sk, Hkv, D))
-        dv = (dvb.transpose(1, 0, 3, 2, 4).reshape(B, Sk, Hkv, D))
+        dv = (dvb.transpose(1, 0, 3, 2, 4).reshape(B, Sk, Hkv, Dv))
         f0 = lambda x: np.zeros(x.shape, jax.dtypes.float0)
         return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
                 f0(q_pos), f0(k_pos))
@@ -367,7 +395,8 @@ def decode_attention_jnp(q, k_cache, v_cache, *, q_position, k_positions,
                          window=0, causal=True):
     """Single-token attention over a (possibly ring-buffered) cache.
 
-    q: (B, 1, Hq, D); caches: (B, Sc, Hkv, D); q_position: (B,);
+    q: (B, 1, Hq, D); caches: (B, Sc, Hkv, D) and (B, Sc, Hkv, Dv);
+    q_position: (B,);
     k_positions: (B, Sc) absolute positions per slot (negative = empty).
     """
     B, _, Hq, D = q.shape
@@ -386,7 +415,7 @@ def decode_attention_jnp(q, k_cache, v_cache, *, q_position, k_positions,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgs,bshd->bhgd", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, 1, Hq, D).astype(q.dtype)
+    return out.reshape(B, 1, Hq, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

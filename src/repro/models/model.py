@@ -72,8 +72,10 @@ def _build_decoder_model(cfg: ModelConfig) -> Model:
             h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
             logits = L.unembed_apply(params["embed"], h, cfg.tie_embeddings)
             ce = L.cross_entropy(logits, batch["labels"])
-        loss = ce + cfg.router_aux_weight * aux
-        return loss, {"ce": ce, "aux": aux}
+        loss = ce + cfg.router_aux_weight * aux["balance"]
+        return loss, {"ce": ce, "aux": aux["balance"],
+                      "held_rows": aux["held_rows"],
+                      "held_peak": aux["held_peak"]}
 
     def prefill_fn(params, batch, *, block_k=1024):
         h, positions, mrope_pos = _decoder_embed_inputs(params, batch, cfg)

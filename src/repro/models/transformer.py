@@ -108,6 +108,32 @@ def _project_qkv(p, x, cfg):
     return q, k, v
 
 
+# the kv latent's RMSNorm epsilon: the default of DeepSeek-V3's published
+# RMSNorm, which its kv_a_layernorm takes (the config's rms_norm_eps is not
+# passed to it)
+LATENT_NORM_EPS = 1e-6
+
+
+def _project_latent(p, x, cfg, positions):
+    """Latent attention's q, k (B, S, H, nope + rope) and v (B, S, H, dv):
+    keys and values expanded from the normed kv latent, the one rotary key
+    head shared by every head, rotary positions in rotate-half form."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope = cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    with jax.named_scope("latent"):
+        kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+        c = L.rmsnorm(kv[..., :r], p["kv_norm"], LATENT_NORM_EPS)
+        kvb = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"])
+        k_pe = L.apply_rope(kv[..., None, r:], positions, theta=cfg.rope_theta)
+        q_pe = L.apply_rope(q[..., nope:], positions, theta=cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_pe], -1)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(k_pe, k_pe.shape[:2] + (H, k_pe.shape[-1]))], -1)
+    return q, k, kvb[..., nope:]
+
+
 def _rope_qk(q, k, cfg, positions, mrope_pos):
     if cfg.mrope and mrope_pos is not None:
         return (L.apply_mrope(q, mrope_pos, theta=cfg.rope_theta),
@@ -124,14 +150,18 @@ def attn_sublayer(p, h, cfg, *, positions, mrope_pos=None, window=0,
     ``positions`` (B, S), or None for ``arange(S)`` in every row."""
     x = L.rmsnorm(h, p["norm"], cfg.norm_eps)
     B, S = h.shape[:2]
+    hd, hkv = ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.num_heads)
+               if cfg.mla else (cfg.head_dim, cfg.num_kv_heads))
     path = L.attention_path(
-        (B, S, cfg.num_heads, cfg.head_dim),
-        (B, S, cfg.num_kv_heads, cfg.head_dim), causal=causal,
+        (B, S, cfg.num_heads, hd), (B, S, hkv, hd), causal=causal,
         window=window, arange=positions is None and mrope_pos is None)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    q, k, v = _project_qkv(p, x, cfg)
-    q, k = _rope_qk(q, k, cfg, positions, mrope_pos)
+    if cfg.mla:
+        q, k, v = _project_latent(p, x, cfg, positions)
+    else:
+        q, k, v = _project_qkv(p, x, cfg)
+        q, k = _rope_qk(q, k, cfg, positions, mrope_pos)
     with jax.named_scope("attention"):
         if path == "kernel":
             out = L.causal_attention_kernel(q, k, v)
@@ -151,13 +181,14 @@ def attn_sublayer_decode(p, h, cfg, cache, *, position, window=0):
     B = h.shape[0]
     Sc = cache["k"].shape[1]
     x = L.rmsnorm(h, p["norm"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, x, cfg)
     pos_b = position[:, None]  # (B,1)
-    if cfg.mrope:
-        mp = jnp.broadcast_to(position[None, :, None], (3, B, 1))
-        q, k = _rope_qk(q, k, cfg, pos_b, mp)
+    if cfg.mla:
+        q, k, v = _project_latent(p, x, cfg, pos_b)
     else:
-        q, k = _rope_qk(q, k, cfg, pos_b, None)
+        q, k, v = _project_qkv(p, x, cfg)
+        mp = (jnp.broadcast_to(position[None, :, None], (3, B, 1))
+              if cfg.mrope else None)
+        q, k = _rope_qk(q, k, cfg, pos_b, mp)
     slot = jnp.where(window > 0, position % Sc, jnp.minimum(position, Sc - 1))
     kc = jax.vmap(lambda c, s, u: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
                   )(cache["k"], slot, k)
@@ -178,8 +209,14 @@ def attn_sublayer_decode(p, h, cfg, cache, *, position, window=0):
 def attn_cache_specs(cfg, B, seq_len, window, prefix=(), dtype=None):
     Sc = min(seq_len, window) if window > 0 else seq_len
     dt = dtype or cfg.dtype
-    sh = prefix + (B, Sc, cfg.num_kv_heads, cfg.head_dim)
-    return {"k": jax.ShapeDtypeStruct(sh, dt), "v": jax.ShapeDtypeStruct(sh, dt)}
+    if cfg.mla:   # expanded keys and values, not the latent
+        H = cfg.num_heads
+        kd = prefix + (B, Sc, H, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+        vd = prefix + (B, Sc, H, cfg.v_head_dim)
+    else:
+        kd = vd = prefix + (B, Sc, cfg.num_kv_heads, cfg.head_dim)
+    return {"k": jax.ShapeDtypeStruct(kd, dt),
+            "v": jax.ShapeDtypeStruct(vd, dt)}
 
 
 # ---------------------------------------------------------------------------
@@ -198,12 +235,25 @@ def mlp_sublayer_specs(cfg, prefix, *, use_moe):
     return out
 
 
+def no_aux():
+    """What a layer adds to the loss's aux: the experts' balance loss and
+    the held experts' load (``models/moe.py``); zero without experts."""
+    zero = jnp.zeros((), jnp.float32)
+    return {"balance": zero, "held_rows": zero, "held_peak": zero}
+
+
 def mlp_sublayer(p, h, cfg, *, use_moe):
     x = L.rmsnorm(h, p["norm"], cfg.norm_eps)
     if use_moe:
         y, aux = M.moe_apply(p, x, cfg)
         return h + y, aux
-    return h + L.mlp_apply(p, x), jnp.zeros((), jnp.float32)
+    return h + L.mlp_apply(p, x), no_aux()
+
+
+def _add_aux(a, b):
+    return {"balance": a["balance"] + b["balance"],
+            "held_rows": a["held_rows"] + b["held_rows"],
+            "held_peak": jnp.maximum(a["held_peak"], b["held_peak"])}
 
 
 # ---------------------------------------------------------------------------
@@ -262,9 +312,10 @@ def layer_kinds(cfg):
 
 
 def _superblock_period(cfg) -> int:
-    """Scan period: smallest p such that layer kinds repeat with period p."""
-    kinds = layer_kinds(cfg)
-    n = cfg.num_layers
+    """Scan period of the stack after the leading dense layers: smallest p
+    such that layer kinds repeat with period p."""
+    kinds = layer_kinds(cfg)[cfg.first_k_dense_replace:]
+    n = len(kinds)
     for p in range(1, n + 1):
         if n % p:
             continue
@@ -273,16 +324,30 @@ def _superblock_period(cfg) -> int:
     return n
 
 
+def _stacks(cfg):
+    """The scanned stacks, in order: ``(params key, kinds of one period,
+    periods)``. Leading dense layers (``first_k_dense_replace``) are a
+    stack of their own, ``"dense"``, before ``"blocks"``."""
+    kinds = layer_kinds(cfg)
+    lead = cfg.first_k_dense_replace
+    out = []
+    if lead:
+        if len(set(kinds[:lead])) != 1:
+            raise ValueError("leading dense layers of more than one kind")
+        out.append(("dense", kinds[:1], lead))
+    period = _superblock_period(cfg)
+    out.append(("blocks", kinds[lead:lead + period],
+                (cfg.num_layers - lead) // period))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # specs for the whole decoder stack
 # ---------------------------------------------------------------------------
 
 
-def decoder_specs(cfg) -> Dict[str, Any]:
-    period = _superblock_period(cfg)
-    n_super = cfg.num_layers // period
-    prefix = (n_super,)
-    kinds = layer_kinds(cfg)[:period]
+def _stack_specs(cfg, kinds, n):
+    prefix = (n,)
     blocks: Dict[str, Any] = {}
     for i, (mixer, use_moe) in enumerate(kinds):
         sub: Dict[str, Any] = {}
@@ -293,11 +358,14 @@ def decoder_specs(cfg) -> Dict[str, Any]:
         if cfg.d_ff or cfg.num_experts:
             sub["mlp"] = mlp_sublayer_specs(cfg, prefix, use_moe=use_moe)
         blocks[f"sub{i}"] = sub
-    specs = {
-        "embed": L.embed_specs(cfg),
-        "blocks": blocks,
-        "final_norm": L.rmsnorm_spec(cfg.d_model),
-    }
+    return blocks
+
+
+def decoder_specs(cfg) -> Dict[str, Any]:
+    specs = {"embed": L.embed_specs(cfg),
+             "final_norm": L.rmsnorm_spec(cfg.d_model)}
+    for key, kinds, n in _stacks(cfg):
+        specs[key] = _stack_specs(cfg, kinds, n)
     return specs
 
 
@@ -306,18 +374,32 @@ def decoder_specs(cfg) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _sub_kinds(cfg):
-    period = _superblock_period(cfg)
-    return layer_kinds(cfg)[:period]
-
-
 def decoder_forward(params, h, cfg, *, positions, mrope_pos=None,
                     collect_cache=False, block_k=1024):
     """Run the full stack over a sequence of hidden states ``h`` (B,S,d).
 
-    Returns (h, aux_loss, cache|None). cache leaves are stacked (n_super,...).
+    Returns (h, aux, cache|None): aux as :func:`no_aux`, summed over the
+    layers (``held_peak`` their largest); cache leaves are stacked
+    (n_super,...), under ``"dense"`` too where leading dense layers are.
     """
-    kinds = _sub_kinds(cfg)
+    ic = {"positions": positions}
+    if mrope_pos is not None:
+        ic["mrope"] = mrope_pos
+    aux, caches = no_aux(), {}
+    for key, kinds, _ in _stacks(cfg):
+        h, (a, c) = _stack_forward(params[key], h, cfg, kinds, ic,
+                                   collect_cache, block_k)
+        aux = _add_aux(aux, {"balance": jnp.sum(a["balance"]),
+                             "held_rows": jnp.sum(a["held_rows"]),
+                             "held_peak": jnp.max(a["held_peak"])})
+        if key == "dense":
+            caches["dense"] = c
+        else:
+            caches.update(c or {})
+    return h, aux, caches if collect_cache else None
+
+
+def _stack_forward(stack, h, cfg, kinds, ic, collect_cache, block_k):
     window = cfg.sliding_window
 
     def superblock(h, block_params, dc, ic):
@@ -325,7 +407,7 @@ def decoder_forward(params, h, cfg, *, positions, mrope_pos=None,
         h = constrain_h(h)
         positions = ic["positions"]
         mrope_pos = ic.get("mrope")
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = no_aux()
         caches = {}
         for i, (mixer, use_moe) in enumerate(kinds):
             sub = block_params[f"sub{i}"]
@@ -343,25 +425,35 @@ def decoder_forward(params, h, cfg, *, positions, mrope_pos=None,
                     caches[f"sub{i}"] = {"state": st[0], "conv_tail": st[1]}
             if "mlp" in sub:
                 h, aux = mlp_sublayer(sub["mlp"], h, cfg, use_moe=use_moe)
-                aux_total = aux_total + aux
+                aux_total = _add_aux(aux_total, aux)
         return h, (aux_total, caches if collect_cache else None)
 
     wrapped = remat_block(superblock)
-    ic = {"positions": positions}
-    if mrope_pos is not None:
-        ic["mrope"] = mrope_pos
 
     def body(h, block_params):
         return wrapped(h, block_params, {}, ic)
 
-    h, (aux, caches) = _scan(body, h, params["blocks"])
-    return h, jnp.sum(aux), caches
+    return _scan(body, h, stack)
 
 
 def decoder_decode_step(params, h, cfg, cache, *, position, window):
     """One-token step through the stack. h: (B,1,d); cache stacked (n_super,…)."""
-    kinds = _sub_kinds(cfg)
+    new_cache = {}
+    for key, kinds, _ in _stacks(cfg):
+        if key == "dense":
+            h, new_cache["dense"] = _stack_decode(
+                params[key], h, cfg, kinds, cache["dense"],
+                position=position, window=window)
+        else:
+            h, c = _stack_decode(
+                params[key], h, cfg, kinds,
+                {k: v for k, v in cache.items() if k != "dense"},
+                position=position, window=window)
+            new_cache.update(c)
+    return h, new_cache
 
+
+def _stack_decode(stack, h, cfg, kinds, cache, *, position, window):
     def superblock(h, inp):
         block_params, block_cache = inp
         new_cache = {}
@@ -380,20 +472,22 @@ def decoder_decode_step(params, h, cfg, cache, *, position, window):
                                     use_moe=kinds[i][1])
         return h, new_cache
 
-    h, new_cache = _scan(superblock, h,
-                         (params["blocks"], cache))
-    return h, new_cache
+    return _scan(superblock, h, (stack, cache))
 
 
 def decoder_cache_specs(cfg, B, seq_len, window, dtype=None):
-    kinds = _sub_kinds(cfg)
-    n_super = cfg.num_layers // len(kinds)
-    prefix = (n_super,)
     out = {}
-    for i, (mixer, _) in enumerate(kinds):
-        if mixer == "attn":
-            out[f"sub{i}"] = attn_cache_specs(cfg, B, seq_len, window,
-                                              prefix, dtype)
+    for key, kinds, n in _stacks(cfg):
+        prefix = (n,)
+        specs = {}
+        for i, (mixer, _) in enumerate(kinds):
+            if mixer == "attn":
+                specs[f"sub{i}"] = attn_cache_specs(cfg, B, seq_len, window,
+                                                    prefix, dtype)
+            else:
+                specs[f"sub{i}"] = ssm_cache_specs(cfg, B, prefix, dtype)
+        if key == "dense":
+            out["dense"] = specs
         else:
-            out[f"sub{i}"] = ssm_cache_specs(cfg, B, prefix, dtype)
+            out.update(specs)
     return out

@@ -13,7 +13,7 @@ from repro.models import build_model
 ASSIGNED = [
     "jamba-v0.1-52b", "qwen2-vl-2b", "mamba2-780m", "mixtral-8x7b",
     "granite-8b", "qwen3-moe-30b-a3b", "yi-34b", "stablelm-1.6b",
-    "moonshot-v1-16b-a3b", "whisper-large-v3",
+    "moonlight-16b-a3b", "whisper-large-v3",
 ]
 
 B, S = 2, 32
@@ -113,7 +113,7 @@ def test_full_config_specs_match_assignment():
     assert c.mrope and c.frontend == "vision" and c.num_heads == 12
     c = get_config("stablelm-1.6b")
     assert c.num_kv_heads == 32 and c.rope_fraction == 0.25
-    c = get_config("moonshot-v1-16b-a3b")
+    c = get_config("moonlight-16b-a3b")
     assert c.num_experts == 64 and c.experts_per_token == 6
     c = get_config("granite-8b")
     assert (c.num_layers, c.d_model) == (36, 4096)
@@ -125,7 +125,7 @@ def test_param_counts_orders_of_magnitude():
         "yi-34b": 34e9, "granite-8b": 8e9, "mixtral-8x7b": 47e9,
         "mamba2-780m": 0.78e9, "stablelm-1.6b": 1.6e9,
         "qwen2-vl-2b": 1.5e9, "jamba-v0.1-52b": 52e9,
-        "qwen3-moe-30b-a3b": 30e9, "moonshot-v1-16b-a3b": 16e9,
+        "qwen3-moe-30b-a3b": 30e9, "moonlight-16b-a3b": 16e9,
     }
     for name, n in expect.items():
         got = get_config(name).param_counts()["total"]

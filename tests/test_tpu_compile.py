@@ -7,6 +7,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under pytest-xdist every worker
 imports this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -204,3 +205,29 @@ def test_splash_attention_compiles_in_worker_body(four_chips):
     fn = jax.jit(shard_map(body, mesh=four_chips, in_specs=P("data"),
                            out_specs=P("data"), axis_names={"data"}))
     _compile(fn, q, q, q)
+
+
+def test_latent_moe_layers_take_both_kernels_on_tpu(one_chip, monkeypatch):
+    """``value_and_grad`` of Moonlight-16B-A3B's leading dense layer and one
+    MoE layer at published widths, with one chip's 8 of the 64 experts and
+    a 2048-token row: on the TPU the latent attention core takes the splash
+    kernel at q·k 192 and values 128, and the routed products XLA's ragged
+    dot kernel. The trace finds each by its instructions' names."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models import layers as L
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("moonlight-16b-a3b").with_(num_layers=2, ep_size=8,
+                                                vocab_size=20480)
+    model = build_model(cfg)
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          model.abstract_params())
+    tok = _sds((1, 2048), jnp.int32, one_chip)
+    before = L.attention_path_counts()
+    fn = jax.jit(jax.value_and_grad(
+        lambda p, b: model.loss_fn(p, b)[0]))
+    text = _compile(fn, params, {"tokens": tok, "labels": tok}).as_text()
+    assert L.attention_path_counts()["kernel"] > before["kernel"]
+    for kernel in ("splash_mha_fwd", "splash_mha_dkv", "ragged-dot"):
+        assert re.search(rf"%{kernel}[\w.-]* = .*? custom-call\(", text), \
+            kernel
