@@ -75,7 +75,8 @@ def _build_decoder_model(cfg: ModelConfig) -> Model:
         loss = ce + cfg.router_aux_weight * aux["balance"]
         return loss, {"ce": ce, "aux": aux["balance"],
                       "held_rows": aux["held_rows"],
-                      "held_peak": aux["held_peak"]}
+                      "held_peak": aux["held_peak"],
+                      "held_tiles": aux["held_tiles"]}
 
     def prefill_fn(params, batch, *, block_k=1024):
         h, positions, mrope_pos = _decoder_embed_inputs(params, batch, cfg)

@@ -5,11 +5,19 @@ A chip that shares an expert layer with ``cfg.ep_size - 1`` others holds
 ``cfg.experts_held`` of the ``num_experts`` routed experts, ``[first,
 first + held)``. It scores every token against all of them with the
 published rule (softmax, or DeepSeek-V3's sigmoid with a correction bias
-that only selects), then groups the assignments that fall on its own
-experts and runs grouped products over just those rows: the work follows
-the rows routed here, not the T·k assignments nor a capacity. What the
-absent experts would add is left to their chips. Shared experts, which
-every chip computes for its own tokens, are added here once.
+that only selects), then sorts the T·k assignments so that the n falling
+on its own experts lead, grouped by expert, in buffers of T·k rows (all
+of them may be held). The grouped products (XLA's ragged dot) visit only
+the groups' row tiles; every other op of the path, forward and pullback,
+runs over the ``ceil(n / row_tile)`` tiles of those n rows: the gather
+into expert order, the SiLU·up activation, the weighted scatter back into
+the tokens. What the products leave in the rows past the held groups
+(unwritten memory on the TPU) reaches no result and no gradient: the row
+work selects those rows away inside its last tile and never visits the
+tiles past it, and writes zeros there, into buffers made zero, so every
+buffer a product reads is zero past n.
+What the absent experts would add is left to their chips. Shared experts,
+which every chip computes for its own tokens, are added here once.
 
 ``GROUPS > 1`` selects the dry-run's capacity dispatch instead
 (``launch/dryrun.py``): tokens in groups sharded over the expert axis,
@@ -17,6 +25,7 @@ each expert's rows in a buffer of fixed capacity, overflow dropped.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -91,51 +100,194 @@ def balance_loss(s, idx, cfg, rows: int):
 # the held experts' products
 # ---------------------------------------------------------------------------
 
-
-@jax.custom_vjp
-def _gather_rows(x, idx, back):
-    """``x[idx]`` whose pullback is a gather too: ``back`` lists, for each
-    row of ``x``, where its copies sit in the result, in (rows, copies)
-    layout, so the cotangent is summed over copies without a scatter."""
-    return x[idx]
+# Rows per tile of the row work: the TPU's ragged dot tiles the rows by 512
+# too, so the tiles this path writes are the tiles the products visit.
+ROW_TILE = 512
 
 
-def _gather_rows_fwd(x, idx, back):
-    return x[idx], (back, x.shape[0])
+def row_tile(m: int) -> int:
+    """The row tile over the T·k = m assignment rows."""
+    return min(ROW_TILE, m)
 
 
-def _gather_rows_bwd(res, ct):
-    back, n = res
-    g = ct[back.reshape(-1)].reshape(n, -1, ct.shape[-1])
-    return g.sum(1, dtype=jnp.float32).astype(ct.dtype), None, None
+def _over_tiles(n, body, init, tile: int):
+    """``body(carry, start, valid)`` over the ``ceil(n / tile)`` row tiles
+    that cover the first n rows of buffers of whole tiles: ``start`` the
+    tile's first row, ``valid`` its rows under n."""
+    def step(i, carry):
+        start = i * tile
+        return body(carry, start, start + jnp.arange(tile) < n)
+
+    return jax.lax.fori_loop(0, (n + tile - 1) // tile, step, init)
 
 
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+def _tile(x, start, tile):
+    return jax.lax.dynamic_slice_in_dim(x, start, tile)
 
 
-def _permute(x, perm, inv):
-    """``x[perm]`` for a permutation ``perm`` with inverse ``inv``."""
-    return _gather_rows(x, perm, inv[:, None])
+def _put(buf, rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(buf, rows.astype(buf.dtype),
+                                               start, 0)
+
+
+def _to_tokens(rows, weight, src, n, T: int):
+    """Σ over the first n rows r of ``rows[r] · weight[r]`` into token
+    ``src[r]``, in f32: (m, d) → (T, d), a scatter-add over the held tiles
+    that reads no row past n."""
+    m, d = rows.shape
+    tile = row_tile(m)
+
+    def body(y, start, valid):
+        r = _tile(rows, start, tile).astype(jnp.float32)
+        if weight is not None:
+            r = r * _tile(weight, start, tile)[:, None]
+        return y.at[_tile(src, start, tile)].add(
+            jnp.where(valid[:, None], r, 0))
+
+    return _over_tiles(n, body, jnp.zeros((T, d), jnp.float32), tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_in(xt, src, n, T: int):
+    """The held rows in expert order: row r < n is ``xt[src[r]]``, the
+    rows past n zero; only the tiles over the first n rows are written,
+    and the pullback sums those rows back into their tokens."""
+    m, d = src.shape[0], xt.shape[1]
+    tile = row_tile(m)
+
+    def body(xs, start, valid):
+        rows = xt[_tile(src, start, tile)]
+        return _put(xs, jnp.where(valid[:, None], rows, 0), start)
+
+    return _over_tiles(n, body, jnp.zeros((m, d), xt.dtype), tile)
+
+
+def _rows_in_fwd(xt, src, n, T):
+    return _rows_in(xt, src, n, T), (src, n)
+
+
+def _rows_in_bwd(T, res, ct):
+    src, n = res
+    return _to_tokens(ct, None, src, n, T).astype(ct.dtype), None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
 
 
 def grouped_matmul(x, w, sizes):
-    """Rows of ``x`` in groups of ``sizes[g]`` rows times ``w[g]``, then
-    the rows of no held group (``sizes[-1]`` of them, last), which give
-    zeros and take no gradient. x (m, a), w (G, a, b), sizes (G + 1,).
-    XLA's ragged dot, which the TPU's compiler turns into a tiled kernel
-    over the groups' rows: custom calls named ``ragged-dot-*``, which
-    carry none of the program's scopes. That kernel leaves the rows past
-    the groups unwritten, in the result and in the rows' gradient, so
-    both are masked here."""
-    held = (jnp.arange(x.shape[0]) < jnp.sum(sizes[:-1]))[:, None]
-    y = jax.lax.ragged_dot(jnp.where(held, x, 0), w, sizes[:-1])
-    return jnp.where(held, y, 0)
+    """Rows of ``x`` in groups of ``sizes[g]`` rows times ``w[g]``; the
+    rows past the groups (``sizes[-1]`` assignments of no held expert,
+    then the padding to whole tiles) no group takes. x (m, a), w (G, a,
+    b), sizes (G + 1,). XLA's ragged dot,
+    which the TPU's compiler turns into a tiled kernel over the groups'
+    rows: custom calls named ``ragged-dot-*``, which carry none of the
+    program's scopes. That kernel visits only the groups' tiles and
+    leaves the rows past the groups unwritten, in the result and in the
+    rows' gradient (the CPU's writes zeros): callers read no row there."""
+    return jax.lax.ragged_dot(x, w, sizes[:-1])
+
+
+@jax.custom_vjp
+def _gate_up(xs, wg, wu, sizes, n):
+    """``silu(xs @ wg[g]) * (xs @ wu[g])`` over the held groups' rows of
+    xs (the first n), as a zero-filled (m, F) buffer: the activation runs
+    over the tiles of the first n rows only, and reads no row past n of
+    the products."""
+    return _gate_up_fwd(xs, wg, wu, sizes, n)[0]
+
+
+def _gate_up_fwd(xs, wg, wu, sizes, n):
+    g, u = grouped_matmul(xs, wg, sizes), grouped_matmul(xs, wu, sizes)
+    tile = row_tile(g.shape[0])
+
+    def body(h, start, valid):
+        gt = _tile(g, start, tile).astype(jnp.float32)
+        a = jax.nn.silu(gt) * _tile(u, start, tile).astype(jnp.float32)
+        return _put(h, jnp.where(valid[:, None], a, 0), start)
+
+    h = _over_tiles(n, body, jnp.zeros(g.shape, g.dtype), tile)
+    return h, (xs, wg, wu, sizes, n, g, u)
+
+
+def _gate_up_bwd(res, dh):
+    xs, wg, wu, sizes, n, g, u = res
+    tile = row_tile(xs.shape[0])
+
+    def body(carry, start, valid):
+        dg, du = carry
+        gt = _tile(g, start, tile).astype(jnp.float32)
+        ut = _tile(u, start, tile).astype(jnp.float32)
+        dht = _tile(dh, start, tile).astype(jnp.float32)
+        sig = jax.nn.sigmoid(gt)
+        v = valid[:, None]
+        dg = _put(dg, jnp.where(v, dht * ut * sig * (1 + gt * (1 - sig)), 0),
+                  start)
+        du = _put(du, jnp.where(v, dht * gt * sig, 0), start)
+        return dg, du
+
+    def add(dx, start, valid):
+        s = _tile(dx, start, tile) + _tile(dxu, start, tile)
+        return _put(dx, jnp.where(valid[:, None], s, 0), start)
+
+    zero = jnp.zeros(g.shape, g.dtype)
+    dg, du = _over_tiles(n, body, (zero, zero), tile)
+    dxg, dwg = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), xs, wg)[1](dg)
+    dxu, dwu = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), xs, wu)[1](du)
+    # the sum is written over dxg: past the held tiles it keeps what the
+    # product left there, which only _rows_in's pullback reads, and that
+    # over the held tiles alone
+    dxs = _over_tiles(n, add, dxg, tile)
+    return dxs, dwg, dwu, None, None
+
+
+_gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, w, order, inv, src, n):
+    """y[t] = Σ_j out[r] · w[t, j] over token t's assignments j to held
+    experts, whose rows r (``order[r] = t·k + j``, ``inv`` its inverse,
+    ``src[r] = t``) lie under n, in f32: (m, d) rows → (T, d). Forward and
+    pullback run over the tiles of the first n rows only."""
+    return _combine_fwd(out, w, order, inv, src, n)[0]
+
+
+def _combine_fwd(out, w, order, inv, src, n):
+    gate = jnp.pad(w.reshape(-1)[order], (0, out.shape[0] - order.shape[0]))
+    y = _to_tokens(out, gate, src, n, w.shape[0])
+    return y, (out, gate, inv, src, n)
+
+
+def _combine_bwd(res, dy):
+    out, gate, inv, src, n = res
+    m, d = out.shape
+    tile = row_tile(m)
+
+    def body(carry, start, valid):
+        dout, dgate = carry
+        dyt = dy[_tile(src, start, tile)]
+        ot = _tile(out, start, tile).astype(jnp.float32)
+        gt = _tile(gate, start, tile)
+        dout = _put(dout, jnp.where(valid[:, None], dyt * gt[:, None], 0),
+                    start)
+        dgate = _put(dgate, jnp.where(valid, jnp.sum(ot * dyt, -1), 0),
+                     start)
+        return dout, dgate
+
+    dout, dgate = _over_tiles(n, body, (jnp.zeros((m, d), out.dtype),
+                                        jnp.zeros((m,), gate.dtype)), tile)
+    # the rows past n hold zero: an absent expert's gate gets none
+    return dout, dgate[inv].reshape(dy.shape[0], -1), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def held_experts(p, xt, idx, gates, first: int = 0):
     """The part of the layer's output that the held experts
     ``[first, first + held)`` give: xt (T, d), the chosen experts and
-    gates (T, k) → ((T, d) f32, rows routed here per held expert)."""
+    gates (T, k) → ((T, d) f32, rows routed here per held expert, row
+    tiles the row work ran over)."""
     T, d = xt.shape
     k = idx.shape[1]
     H = p["wi_gate"].shape[0]
@@ -148,22 +300,26 @@ def held_experts(p, xt, idx, gates, first: int = 0):
         sizes = jnp.bincount(key, length=H + 1).astype(jnp.int32)
         w = jnp.where(held.reshape(T, k), gates, 0.0)
     with jax.named_scope("experts"):
-        # the T·k assignments in expert order: held rows lead, the rest
-        # give zeros
-        xs = _gather_rows(xt, order // k, inv.reshape(T, k))
-        h = jax.nn.silu(grouped_matmul(xs, p["wi_gate"], sizes)) * \
-            grouped_matmul(xs, p["wi_up"], sizes)
+        # the T·k assignments in expert order, in buffers of whole row
+        # tiles: the n held rows lead, and the row work runs over the
+        # tiles of those rows alone
+        m = T * k
+        tile = row_tile(m)
+        n = jnp.sum(sizes[:H])
+        src = jnp.pad(order // k, (0, -m % tile))
+        xs = _rows_in(xt, src, n, T)
+        h = _gate_up(xs, p["wi_gate"], p["wi_up"], sizes, n)
         out = grouped_matmul(h, p["wo"], sizes)
-        back = _permute(out, inv, order).reshape(T, k, d)
-        y = jnp.einsum("tkd,tk->td", back, w.astype(back.dtype),
-                       preferred_element_type=jnp.float32)
-    return y, sizes[:H]
+        y = _combine(out, w, order, inv, src, n)
+        tiles = (n + tile - 1) // tile
+    return y, sizes[:H], tiles
 
 
 def moe_apply(p, x, cfg, *, first: int = 0):
     """x: (B, S, d) → (B, S, d), aux: the balance loss and the held
     experts' load (``held_rows``, rows routed here; ``held_peak``, the
-    busiest held expert's rows over the held mean)."""
+    busiest held expert's rows over the held mean; ``held_tiles``, the
+    row tiles the row work ran over, ``ceil(held_rows / row_tile(T·k))``)."""
     if GROUPS > 1:
         return capacity_apply(p, x, cfg)
     B, S, d = x.shape
@@ -171,12 +327,13 @@ def moe_apply(p, x, cfg, *, first: int = 0):
     with jax.named_scope("router"):
         s, idx, gates = route(xt, p, cfg)
         balance = balance_loss(s, idx, cfg, B)
-    y, load = held_experts(p, xt, idx, gates, first)
+    y, load, tiles = held_experts(p, xt, idx, gates, first)
     if "shared" in p:
         y = y + mlp_apply(p["shared"], xt).astype(jnp.float32)
     rows = jnp.sum(load).astype(jnp.float32)
     aux = {"balance": balance, "held_rows": rows,
-           "held_peak": jnp.max(load) * load.shape[0] / jnp.maximum(rows, 1)}
+           "held_peak": jnp.max(load) * load.shape[0] / jnp.maximum(rows, 1),
+           "held_tiles": tiles.astype(jnp.float32)}
     return y.astype(x.dtype).reshape(B, S, d), aux
 
 
@@ -287,4 +444,5 @@ def capacity_apply(p, x, cfg):
     balance = balance_loss(probs.reshape(T, E), gate_idx.reshape(T, k), cfg,
                            B)
     zero = jnp.zeros((), jnp.float32)
-    return y, {"balance": balance, "held_rows": zero, "held_peak": zero}
+    return y, {"balance": balance, "held_rows": zero, "held_peak": zero,
+               "held_tiles": zero}

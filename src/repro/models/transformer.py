@@ -239,7 +239,8 @@ def no_aux():
     """What a layer adds to the loss's aux: the experts' balance loss and
     the held experts' load (``models/moe.py``); zero without experts."""
     zero = jnp.zeros((), jnp.float32)
-    return {"balance": zero, "held_rows": zero, "held_peak": zero}
+    return {"balance": zero, "held_rows": zero, "held_peak": zero,
+            "held_tiles": zero}
 
 
 def mlp_sublayer(p, h, cfg, *, use_moe):
@@ -253,7 +254,8 @@ def mlp_sublayer(p, h, cfg, *, use_moe):
 def _add_aux(a, b):
     return {"balance": a["balance"] + b["balance"],
             "held_rows": a["held_rows"] + b["held_rows"],
-            "held_peak": jnp.maximum(a["held_peak"], b["held_peak"])}
+            "held_peak": jnp.maximum(a["held_peak"], b["held_peak"]),
+            "held_tiles": a["held_tiles"] + b["held_tiles"]}
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +393,8 @@ def decoder_forward(params, h, cfg, *, positions, mrope_pos=None,
                                    collect_cache, block_k)
         aux = _add_aux(aux, {"balance": jnp.sum(a["balance"]),
                              "held_rows": jnp.sum(a["held_rows"]),
-                             "held_peak": jnp.max(a["held_peak"])})
+                             "held_peak": jnp.max(a["held_peak"]),
+                             "held_tiles": jnp.sum(a["held_tiles"])})
         if key == "dense":
             caches["dense"] = c
         else:

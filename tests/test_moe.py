@@ -29,6 +29,28 @@ def _x(rng, i, shape, scale=1.0):
     return jax.random.normal(jax.random.fold_in(rng, i), shape) * scale
 
 
+# held shares of the T·k assignments: (ep_size, first held expert, bias on
+# it). A bias of -10 sends every token elsewhere, so no row is held.
+SHARES = {"none": (8, 3, -10.0), "eighth": (8, 5, 0.0), "half": (2, 4, 0.0),
+          "all": (1, 0, 0.0)}
+# the layer's own row tile, and 7 rows: the held counts above (0, 8, 60
+# and 128 of 128 rows at 2 x 32 tokens) are then no multiple of the tile
+TILES = (None, 7)
+
+
+def _held_case(rng, share, tile, monkeypatch):
+    """A tiny Moonlight layer holding the given share of its rows, and
+    the row tile set for the test: (cfg, params, first, x)."""
+    ep, first, bias = SHARES[share]
+    if tile is not None:
+        monkeypatch.setattr(M, "ROW_TILE", tile)
+    cfg = _moonlight(ep_size=ep)
+    p = _params(rng, cfg)
+    p["bias"] = jnp.zeros_like(p["bias"]).at[first].set(bias)
+    x = _x(rng, 21, (2, 32, cfg.d_model), 0.5)
+    return cfg, p, first, x
+
+
 def _share(p, first, held):
     """The weights chip ``first // held`` holds of an uncut layer's."""
     q = dict(p)
@@ -157,16 +179,35 @@ class TestHeldExperts:
         np.testing.assert_allclose(np.asarray(total), np.asarray(y_whole),
                                    rtol=1e-4, atol=1e-5)
 
-    def test_held_part_matches_dense_oracle(self, rng):
-        cfg = _moonlight(ep_size=4)
-        p = _params(rng, cfg)
-        x = _x(rng, 21, (2, 16, cfg.d_model), 0.5)
-        for first in (0, 2, 6):
-            y, _ = M.moe_apply(p, x, cfg, first=first)
-            np.testing.assert_allclose(
-                np.asarray(y), np.asarray(M.moe_apply_dense(p, x, cfg,
-                                                            first=first)),
-                rtol=1e-4, atol=1e-5)
+    @pytest.mark.parametrize("tile", TILES)
+    @pytest.mark.parametrize("share", list(SHARES))
+    def test_held_part_matches_dense_oracle(self, rng, monkeypatch, share,
+                                            tile):
+        cfg, p, first, x = _held_case(rng, share, tile, monkeypatch)
+        y, _ = M.moe_apply(p, x, cfg, first=first)
+        np.testing.assert_allclose(
+            np.asarray(y), np.asarray(M.moe_apply_dense(p, x, cfg,
+                                                        first=first)),
+            rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("tile", TILES)
+    @pytest.mark.parametrize("share", list(SHARES))
+    def test_held_tiles_follow_the_held_rows(self, rng, monkeypatch, share,
+                                             tile):
+        """The row work runs over ceil(held rows / tile) tiles per call:
+        none with no row held, every tile of the T·k buffer with all."""
+        cfg, p, first, x = _held_case(rng, share, tile, monkeypatch)
+        _, aux = M.moe_apply(p, x, cfg, first=first)
+        m = x.shape[0] * x.shape[1] * cfg.experts_per_token
+        t = M.row_tile(m)
+        rows, tiles = int(aux["held_rows"]), int(aux["held_tiles"])
+        assert tiles == -(-rows // t) <= -(-m // t)
+        if share == "none":
+            assert rows == tiles == 0
+        if share == "all":
+            assert rows == m and tiles == -(-m // t)
+        if tile is not None and rows:
+            assert rows % t, "a held count that is a multiple of the tile"
 
     def test_no_row_dropped_when_every_token_routes_to_one_expert(self, rng):
         """A bias that sends every token to expert 0: all T rows land on
@@ -258,14 +299,15 @@ class TestGroupedProducts:
         y = M.grouped_matmul(x, w, sizes)
         assert float(jnp.abs(y[140:]).max()) == 0.0
 
-    def test_layer_grads_match_dense_oracle(self, rng):
-        """The held part's gradients, through the permutes' gather-only
-        pullbacks and the grouped products, equal the dense oracle's."""
-        cfg = _moonlight(ep_size=4)
-        p = _params(rng, cfg)
-        x = _x(rng, 32, (2, 32, cfg.d_model), 0.5)
+    @pytest.mark.parametrize("tile", TILES)
+    @pytest.mark.parametrize("share", list(SHARES))
+    def test_layer_grads_match_dense_oracle(self, rng, monkeypatch, share,
+                                            tile):
+        """The held part's gradients, through the row work's pullbacks
+        over the held tiles and the grouped products, equal the dense
+        oracle's."""
+        cfg, p, first, x = _held_case(rng, share, tile, monkeypatch)
         w = _x(rng, 33, x.shape)
-        first = cfg.experts_held
 
         def loss(f):
             return lambda p, x: jnp.sum(f(p, x) * w)
@@ -307,6 +349,58 @@ class TestGroupedProducts:
             _, pull = jax.vjp(lambda a, b: real(a, b, sizes), a, b)
             da, db = pull(ct)
             return junk(da, sizes), db, None
+
+        dirty.defvjp(dirty_fwd, dirty_bwd)
+
+        def run():
+            return jax.value_and_grad(lambda p, x: jnp.sum(M.moe_apply(
+                p, x, cfg, first=cfg.experts_held)[0] * w), (0, 1))(p, x)
+
+        want = run()
+        monkeypatch.setattr(jax.lax, "ragged_dot", dirty)
+        got = run()
+        assert float(got[0]) == float(want[0])
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_rows_past_the_held_groups_never_reach_the_layer_in_small_tiles(
+            self, rng, monkeypatch):
+        """The same junk, with a row tile of 7: the held rows end inside a
+        tile and past it lie tiles the row work never writes."""
+        monkeypatch.setattr(M, "ROW_TILE", 7)
+        self.test_rows_past_the_held_groups_never_reach_the_layer(
+            rng, monkeypatch)
+
+    @pytest.mark.parametrize("tile", TILES)
+    def test_nan_past_the_held_groups_never_reaches_the_layer(
+            self, rng, monkeypatch, tile):
+        """NaN in the rows past the held groups, in the products and in
+        their rows' gradients: a zero gate or a zero cotangent does not
+        hide it, so the row work must read none of those rows."""
+        if tile is not None:
+            monkeypatch.setattr(M, "ROW_TILE", tile)
+        cfg = _moonlight(ep_size=4)
+        p = _params(rng, cfg)
+        x = _x(rng, 36, (2, 32, cfg.d_model), 0.5)
+        w = _x(rng, 37, x.shape)
+        real = jax.lax.ragged_dot
+
+        def nan_past(a, sizes):
+            past = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(past, jnp.nan, a)
+
+        @jax.custom_vjp
+        def dirty(a, b, sizes):
+            return nan_past(real(a, b, sizes), sizes)
+
+        def dirty_fwd(a, b, sizes):
+            return dirty(a, b, sizes), (a, b, sizes)
+
+        def dirty_bwd(res, ct):
+            a, b, sizes = res
+            _, pull = jax.vjp(lambda a, b: real(a, b, sizes), a, b)
+            da, db = pull(ct)
+            return nan_past(da, sizes), db, None
 
         dirty.defvjp(dirty_fwd, dirty_bwd)
 
